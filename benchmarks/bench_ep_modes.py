@@ -1,4 +1,5 @@
-"""EP execution-mode comparison on *our* TPU system (not the simulator).
+"""EP execution-mode comparison through the real JAX EP code (not the
+simulator), on the CPU: no number here is a device time.
 
 Lowers the paper-style MoE block through the real shard_map EP paths on an
 8-device (forced-host) CPU mesh in a subprocess and reports, from the
@@ -12,8 +13,6 @@ from __future__ import annotations
 import os
 import subprocess
 import sys
-
-from .common import emit
 
 _SUB = r"""
 import os
@@ -53,19 +52,20 @@ print("ep_modes_numerics,0.00,baseline==hyperparallel allclose ok")
 
 
 def run() -> None:
-    env = dict(os.environ)
+    # The child measures a forced-host CPU mesh. Pinning it to the CPU
+    # keeps it off an accelerator that this process may already hold.
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-c", _SUB],
         cwd=os.path.join(os.path.dirname(__file__), ".."),
         env=env, capture_output=True, text=True, timeout=900)
-    ok = False
+    if out.returncode != 0:
+        raise RuntimeError(f"ep_modes child exited {out.returncode}: "
+                           f"{out.stderr.strip()[-2000:]}")
     for line in out.stdout.splitlines():
         if line.startswith(("ep_mode", "ep_modes")):
             print(line)
-            ok = True
-    if not ok:
-        emit("ep_modes_failed", 0.0, out.stderr.strip()[-200:])
 
 
 if __name__ == "__main__":

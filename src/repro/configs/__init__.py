@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import dataclasses
 from importlib import import_module
 
 ARCHS = [
@@ -28,17 +29,16 @@ def canonical(arch: str) -> str:
     return _ALIASES.get(arch, arch)
 
 
+def _build(arch: str, factory: str, overrides: dict):
+    mod = import_module(
+        f"repro.configs.{canonical(arch).replace('-', '_')}")
+    cfg = getattr(mod, factory)()
+    return dataclasses.replace(cfg, **overrides) if overrides else cfg
+
+
 def get_config(arch: str, **overrides):
-    mod = import_module(
-        f"repro.configs.{canonical(arch).replace('-', '_')}")
-    cfg = mod.config()
-    if overrides:
-        import dataclasses
-        cfg = dataclasses.replace(cfg, **overrides)
-    return cfg
+    return _build(arch, "config", overrides)
 
 
-def get_smoke_config(arch: str):
-    mod = import_module(
-        f"repro.configs.{canonical(arch).replace('-', '_')}")
-    return mod.smoke_config()
+def get_smoke_config(arch: str, **overrides):
+    return _build(arch, "smoke_config", overrides)

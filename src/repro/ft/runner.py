@@ -172,8 +172,14 @@ def train_loop(*, step_fn, params, opt_state, stream, mesh, batch_sharding,
     elastic_events: list = []
     latest = CK.latest_step_dir(ft.ckpt_dir)
     if latest is not None:
+        # Restore each leaf straight onto the live state's sharding, so a
+        # step compiled for those shardings accepts the restored state.
+        leaves = jax.tree.leaves((params, opt_state))
+        shardings = (jax.tree.map(lambda a: a.sharding, (params, opt_state))
+                     if all(isinstance(a, jax.Array) for a in leaves)
+                     else None)
         (params, opt_state), manifest = CK.restore(
-            latest, (params, opt_state))
+            latest, (params, opt_state), shardings)
         start_step = manifest["step"]
         resumed_from = start_step
         extra = manifest.get("extra") or {}

@@ -1,13 +1,15 @@
-"""Grouped GEMM Pallas kernel — expert-block tiles, full-K reduction.
+"""Grouped GEMM Pallas kernel — expert-block tiles, K reduction in VMEM.
 
 The paper's GMM decomposition constraint (§4.2): task-level parallelism only
 along token/expert-block dimensions; the K reduction stays intact so the
 accumulation structure and expert-local layout survive. On TPU that maps to
-a grid over (expert, M-tile, N-tile) with K kept whole inside the tile —
-each tile is one MXU-aligned matmul with both operands VMEM-resident.
+a grid over (expert, M-tile, N-tile) with K whole inside the tile when it
+fits VMEM, and otherwise a trailing K axis that accumulates into an fp32
+VMEM scratch tile (never through HBM).
 
-Block shapes default to MXU-friendly multiples of 128; ``bm × K`` and
-``K × bn`` must fit VMEM (~128 MB), checked at call time.
+Block sizes come from :mod:`.tiling`: rows are padded up to a multiple of
+the row block, lane blocks are 128-aligned, and the working set is checked
+against the v5e kernel VMEM limit.
 """
 
 from __future__ import annotations
@@ -17,47 +19,50 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-def _pick_block(dim: int, pref: int) -> int:
-    """Largest divisor of ``dim`` that is ≤ ``pref`` (hardware-aligned when
-    possible — callers pass multiples of 128)."""
-    b = min(pref, dim)
-    while dim % b:
-        b -= 1
-    return b
-
-
+from . import tiling as T
 from .ref import gmm_ref  # noqa: F401  (oracle lives alongside)
 
 
-def _gmm_kernel(x_ref, w_ref, o_ref):
-    # x_ref: [1, bm, K]; w_ref: [1, K, bn]; o_ref: [1, bm, bn]
-    x = x_ref[0]
-    w = w_ref[0]
-    o_ref[0, :, :] = jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref):
+    # x_ref: [1, bm, bk]; w_ref: [1, bk, bn]; o_ref: [1, bm, bn]
+    k = pl.program_id(3)
+
+    @pl.when(k == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    acc_ref[...] += T.mxu_dot(x_ref[0], w_ref[0])
+
+    @pl.when(k == pl.num_programs(3) - 1)
+    def _out():
+        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
 def gmm(x, w, *, bm: int = 128, bn: int = 128, interpret: bool = False):
     """x: [E, C, K] expert-grouped tokens; w: [E, K, N] → [E, C, N]."""
     E, C, K = x.shape
-    _, _, N = w.shape
-    bm = _pick_block(C, bm)
-    bn = _pick_block(N, bn)
-    vmem = (bm * K + K * bn + bm * bn) * x.dtype.itemsize
-    assert vmem < 100 * 2**20, f"tile working set {vmem} exceeds VMEM budget"
+    N = w.shape[-1]
+    bm, Cp = T.row_block(C, bm)
+    bn = T.lane_block(N, bn)
+    it = x.dtype.itemsize
+    bk = T.fit_k(K, lambda bk: (2 * (bm * bk + bk * bn) * it
+                                + 2 * bm * bn * it + 2 * bm * bn * 4), "gmm")
 
-    grid = (E, C // bm, N // bn)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gmm_kernel,
-        grid=grid,
+        grid=(E, Cp // bm, N // bn, K // bk),
         in_specs=[
-            pl.BlockSpec((1, bm, K), lambda e, i, j: (e, i, 0)),
-            pl.BlockSpec((1, K, bn), lambda e, i, j: (e, 0, j)),
+            pl.BlockSpec((1, bm, bk), lambda e, i, j, k: (e, i, k)),
+            pl.BlockSpec((1, bk, bn), lambda e, i, j, k: (e, k, j)),
         ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda e, i, j: (e, i, j)),
-        out_shape=jax.ShapeDtypeStruct((E, C, N), x.dtype),
+        out_specs=pl.BlockSpec((1, bm, bn), lambda e, i, j, k: (e, i, j)),
+        out_shape=jax.ShapeDtypeStruct((E, Cp, N), x.dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=T.compiler_params(
+            "parallel", "parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(x, w)
+    )(T.pad_rows(x, Cp), w)
+    return out[:, :C]

@@ -8,35 +8,20 @@ dry-run pins ``xla_force_host_platform_device_count`` before first init.
 from __future__ import annotations
 
 import jax
-
-
-def _axis_types_kw(n: int) -> dict:
-    """axis_types kwarg where supported (jax ≥ 0.5); empty dict otherwise."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {} if at is None else {"axis_types": (at.Auto,) * n}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single pod (256 chips) or 2×16×16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(data: int = 2, model: int = 4):
     """Small mesh for multi-device CPU tests (needs forced host devices)."""
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_types_kw(2))
-
-
-def mesh_context(mesh):
-    """Context manager that makes ``mesh`` ambient for PartitionSpec-based
-    ``with_sharding_constraint`` calls: ``jax.set_mesh`` on jax ≥ 0.5,
-    falling back to the ``Mesh`` object itself (a context manager) on 0.4.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

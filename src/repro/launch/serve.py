@@ -300,7 +300,9 @@ def main():
             ap.error(str(e))
 
     from repro.configs import get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import model as M
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch)
     if args.sched:
         resolve_decode_sched(cfg, args.sched, args.slots)

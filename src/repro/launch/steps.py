@@ -149,13 +149,18 @@ def make_steps(cfg, mesh, *, opt: Optional[adamw.OptConfig] = None,
 # ---------------------------------------------------------------------------
 
 
+def state_shardings(rules: ShardingRules, params_shape):
+    """(param shardings, optimizer-state shardings) for a params shape tree.
+    ZeRO-1 modes shard the optimizer state even where params replicate."""
+    ps = rules.param_shardings(params_shape)
+    oss = rules.opt_state_shardings(params_shape)
+    return ps, {"m": oss, "v": oss, "master": oss,
+                "step": NamedSharding(rules.mesh, P())}
+
+
 def jit_train_step(fns: StepFns, params_shape, batch_shapes):
     rules = fns.rules
-    ps = rules.param_shardings(params_shape)
-    # ZeRO-1 modes shard the optimizer state even where params replicate.
-    oss = rules.opt_state_shardings(params_shape)         if hasattr(rules, "opt_state_shardings") else ps
-    os_ = {"m": oss, "v": oss, "master": oss,
-           "step": NamedSharding(rules.mesh, P())}
+    ps, os_ = state_shardings(rules, params_shape)
     bs = rules.batch_shardings(batch_shapes)
     return jax.jit(
         fns.train_step,

@@ -9,13 +9,51 @@ straggler watchdog.
     PYTHONPATH=src python -m repro.launch.train \
         --arch granite-moe-3b-a800m --smoke --force-devices 8 \
         --mesh 2x4 --mode ep_dp --steps 20
+
+On one TPU chip, at a model's published widths with its depth cut to fit:
+
+    PYTHONPATH=src python -m repro.launch.train \
+        --arch granite-moe-3b-a800m --layers 4 --mesh 1x1 \
+        --global-batch 2 --seq 2048 --steps 5
 """
 
 from __future__ import annotations
 
 import argparse
 import os
-import sys
+import time
+
+
+def build_training(cfg, mesh, *, oc, ep, mode, dropless, global_batch, seq):
+    """Returns ``(fns, step, init, state_shape, batch_shapes)``.
+
+    ``step`` is the sharded jitted train step. ``init()`` is jitted with the
+    state's shardings as ``out_shardings``, so the parameters and optimizer
+    state are born sharded: no device ever holds more of them than its
+    sharding gives it.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from repro.launch import steps as St
+    from repro.models import model as M
+    from repro.optim import adamw
+
+    fns = St.make_steps(cfg, mesh, opt=oc, ep=ep, mode=mode,
+                        dropless=dropless)
+
+    def init_state():
+        params = adamw.cast_params(
+            M.init_params(cfg, jax.random.PRNGKey(0)), cfg.compute_dtype)
+        return params, adamw.init_opt_state(params)
+
+    state_shape = jax.eval_shape(init_state)
+    batch_shapes = {k: jax.ShapeDtypeStruct((global_batch, seq), jnp.int32)
+                    for k in ("tokens", "labels")}
+    step = St.jit_train_step(fns, state_shape[0], batch_shapes)
+    init = jax.jit(init_state, out_shardings=St.state_shardings(
+        fns.rules, state_shape[0]))
+    return fns, step, init, state_shape, batch_shapes
 
 
 def main(argv=None):
@@ -23,6 +61,9 @@ def main(argv=None):
     ap.add_argument("--arch", default="granite-moe-3b-a800m")
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced smoke config (CPU-sized)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the model's depth to N layers (0 = the "
+                         "config's own); widths are never changed")
     ap.add_argument("--mesh", default="2x4",
                     help="dataxmodel (or podxdataxmodel)")
     ap.add_argument("--mode", default="tp_sp",
@@ -65,25 +106,25 @@ def main(argv=None):
             f"--xla_force_host_platform_device_count={args.force_devices}")
 
     import jax
-    import jax.numpy as jnp
 
     from repro.configs import get_config, get_smoke_config
+    from repro.core.passes import pipeline_arg as resolve_sched_arg
     from repro.data.pipeline import DataConfig, SyntheticStream
     from repro.ft.runner import FTConfig, train_loop
-    from repro.launch import steps as St
-    from repro.models import model as M
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.optim import adamw
     from repro.parallel.ep import EPConfig
 
-    from repro.core.passes import pipeline_arg as resolve_sched_arg
-    from repro.launch.mesh import _axis_types_kw, mesh_context
-
+    enable_compile_cache()
     dims = [int(x) for x in args.mesh.split("x")]
     names = (("pod", "data", "model") if len(dims) == 3
              else ("data", "model"))
-    mesh = jax.make_mesh(tuple(dims), names, **_axis_types_kw(len(dims)))
+    mesh = jax.make_mesh(tuple(dims), names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(dims))
 
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    overrides = {"n_layers": args.layers} if args.layers else {}
+    cfg = (get_smoke_config if args.smoke else get_config)(
+        args.arch, **overrides)
     if cfg.family == "moe":
         # Pad experts so E % model-axis == 0 (router never selects padding).
         import dataclasses
@@ -130,30 +171,17 @@ def main(argv=None):
         print(f"dropless shape buckets: {bucket}")
         if sched_pipeline is not None:
             print(f"dropless schedule pipeline: {dropless.pipeline!r}")
-    fns = St.make_steps(cfg, mesh, opt=oc, ep=ep, mode=args.mode,
-                        dropless=dropless)
-
-    params = adamw.cast_params(M.init_params(cfg, jax.random.PRNGKey(0)),
-                               cfg.compute_dtype)
-    opt_state = adamw.init_opt_state(params)
-    params_shape = jax.eval_shape(lambda: params)
-    batch_shapes = {
-        "tokens": jax.ShapeDtypeStruct(
-            (args.global_batch, args.seq), jnp.int32),
-        "labels": jax.ShapeDtypeStruct(
-            (args.global_batch, args.seq), jnp.int32)}
-    with mesh_context(mesh):
-        step = St.jit_train_step(fns, params_shape, batch_shapes)
-        ps = fns.rules.param_shardings(params_shape)
-        oss = fns.rules.opt_state_shardings(params_shape)
-        params = jax.device_put(params, ps)
-        opt_state = {
-            "m": jax.device_put(opt_state["m"], oss),
-            "v": jax.device_put(opt_state["v"], oss),
-            "master": jax.device_put(opt_state["master"], oss),
-            "step": jax.device_put(
-                opt_state["step"],
-                jax.NamedSharding(mesh, jax.sharding.PartitionSpec()))}
+    print(f"model {cfg.name}: {cfg.n_layers} layers, d_model "
+          f"{cfg.d_model}, {cfg.param_count() / 1e9:.3f}B params; mesh "
+          f"{args.mesh} ({args.mode}), batch {args.global_batch}x{args.seq}")
+    fns, step, init, state_shape, batch_shapes = build_training(
+        cfg, mesh, oc=oc, ep=ep, mode=args.mode, dropless=dropless,
+        global_batch=args.global_batch, seq=args.seq)
+    with jax.set_mesh(mesh):
+        t0 = time.perf_counter()
+        step = step.lower(*state_shape, batch_shapes).compile()
+        print(f"train step compiled in {time.perf_counter() - t0:.1f}s")
+        params, opt_state = init()
 
         stream = SyntheticStream(DataConfig(
             vocab=cfg.vocab, seq_len=args.seq,
@@ -169,13 +197,14 @@ def main(argv=None):
             stream=_Stream(), mesh=mesh, batch_sharding=None,
             n_steps=args.steps,
             ft=FTConfig(ckpt_dir=args.ckpt_dir,
-                        ckpt_every=args.ckpt_every), log_every=5)
+                        ckpt_every=args.ckpt_every),
+            log_every=1)
 
     if run.resumed_from is not None:
         print(f"resumed from step {run.resumed_from}")
     for m in run.metrics_log:
-        print(f"step {m['step']:4d} loss {m['loss']:.4f} "
-              f"gnorm {m['grad_norm']:.3f} {m['step_time_s']*1e3:.0f}ms")
+        print(f"step {m['step']:4d} loss {m['loss']:.6f} "
+              f"gnorm {m['grad_norm']:.6f} {m['step_time_s'] * 1e3:.3f}ms")
     if run.stragglers:
         print("stragglers:", run.stragglers)
     if fns.dropless is not None:

@@ -158,7 +158,7 @@ def test_dropless_impl_matches_grouped(bucket):
 def test_train_step_loss_parity_and_cache_reuse():
     from repro.configs import get_smoke_config
     from repro.launch import steps as St
-    from repro.launch.mesh import make_test_mesh, mesh_context
+    from repro.launch.mesh import make_test_mesh
     from repro.optim import adamw
     from repro.models import model as M
 
@@ -177,7 +177,7 @@ def test_train_step_loss_parity_and_cache_reuse():
     drop = St.make_steps(cfg, mesh, opt=oc, mode="zero1",
                          dropless=DroplessConfig(ep=2, bucket_rows=4))
     assert drop.dropless is not None and fixed.dropless is None
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         p1, _, m1 = fixed.train_step(params, opt_state, batch)
         p2, o2, m2 = drop.train_step(params, opt_state, batch)
         np.testing.assert_allclose(float(m1["loss"]), float(m2["loss"]),
@@ -304,8 +304,6 @@ print("RAGGED_SKIP_OK")
 
 
 def test_ragged_ep_subprocess():
-    if not hasattr(jax, "set_mesh") or not hasattr(jax, "shard_map"):
-        pytest.skip("shard_map/set_mesh EP path needs jax >= 0.5")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(

@@ -16,6 +16,7 @@ SHAPES_GMM = [
     (4, 256, 192, 256),
     (3, 64, 96, 160),      # non-128-multiple N
     (8, 512, 128, 64),
+    (2, 200, 256, 384),    # rows padded up to the row block
 ]
 DTYPES = [jnp.float32, jnp.bfloat16]
 
@@ -82,14 +83,52 @@ def test_moe_expert_ffn_drop_in():
 
 
 def test_vmem_budget_guard():
+    # K=60000 is not a multiple of 128, so it cannot be tiled, and whole it
+    # does not fit the kernel VMEM limit.
     x = jnp.zeros((1, 128, 60000), jnp.float32)
     w = jnp.zeros((1, 60000, 512), jnp.float32)
-    with pytest.raises(AssertionError, match="VMEM"):
+    with pytest.raises(ValueError, match="VMEM"):
         gmm(x, w, bm=128, bn=512, interpret=True)
 
 
+def test_gmm_tiles_k_that_does_not_fit_whole():
+    """A K too large for one VMEM block is reduced over K tiles."""
+    from repro.kernels import tiling
+    K = 8192
+    ws = lambda bk: 2 * (128 * bk + bk * 512) * 4     # noqa: E731
+    assert ws(K) > tiling.VMEM_LIMIT_BYTES
+    assert tiling.fit_k(K, ws, "t") < K
+    k1, k2 = jax.random.split(jax.random.PRNGKey(8))
+    x = jax.random.normal(k1, (1, 128, K), jnp.float32)
+    w = jax.random.normal(k2, (1, K, 512), jnp.float32) * 0.01
+    np.testing.assert_allclose(
+        np.asarray(gmm(x, w, bm=128, bn=512, interpret=True)),
+        np.asarray(ref.gmm_ref(x, w)), rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("rows,pref", [(2736, 128), (5, 128), (64, 128),
+                                       (1000, 256), (130, 8)])
+def test_row_block_aligned_and_covering(rows, pref):
+    from repro.kernels.tiling import row_block
+    bm, padded = row_block(rows, pref)
+    assert bm % 8 == 0 and bm <= max(pref, 8)
+    assert padded % bm == 0 and rows <= padded < rows + bm
+
+
+@pytest.mark.parametrize("dim,pref", [(1536, 128), (512, 512), (7168, 512),
+                                      (2048, 384), (160, 128), (64, 128)])
+def test_lane_blocks_aligned_or_whole(dim, pref):
+    from repro.kernels.tiling import lane_blocks
+    blocks = lane_blocks(dim, pref)
+    assert blocks == sorted(blocks, reverse=True)
+    for b in blocks:
+        assert dim % b == 0
+        assert b == dim or (b % 128 == 0 and b <= pref)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32])
-@pytest.mark.parametrize("E,C,K,F", [(2, 128, 64, 128), (3, 64, 96, 64)])
+@pytest.mark.parametrize("E,C,K,F", [(2, 128, 64, 128), (3, 64, 96, 64),
+                                     (2, 100, 128, 256)])
 def test_gmm_swiglu_custom_vjp(E, C, K, F, dtype):
     """Pallas backward kernels == jax.vjp of the jnp oracle."""
     from repro.kernels.gmm_swiglu_bwd import gmm_swiglu_trainable
@@ -108,6 +147,33 @@ def test_gmm_swiglu_custom_vjp(E, C, K, F, dtype):
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_ref),
                                rtol=1e-4, atol=1e-4)
+
+
+def test_gmm_swiglu_vjp_tiles_k(monkeypatch):
+    """Under a VMEM limit that all of K exceeds, the forward and the three
+    backward kernels each reduce over K tiles and still match the oracle."""
+    from repro.kernels import tiling
+    from repro.kernels.gmm_swiglu_bwd import gmm_swiglu_trainable
+    E, C, K, F = 1, 64, 512, 128
+    fit_k, blocks = tiling.fit_k, []
+
+    def spy(K, working_set, what):
+        blocks.append(fit_k(K, working_set, what))
+        return blocks[-1]
+
+    monkeypatch.setattr(tiling, "VMEM_LIMIT_BYTES", 2**20)
+    monkeypatch.setattr(tiling, "fit_k", spy)
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(9), 3)
+    x = jax.random.normal(k1, (E, C, K), jnp.float32)
+    w = jax.random.normal(k2, (E, K, 2 * F), jnp.float32) * 0.05
+    dout = jax.random.normal(k3, (E, C, F), jnp.float32)
+    out, vjp = jax.vjp(lambda x, w: gmm_swiglu_trainable(x, w, True), x, w)
+    grads = vjp(dout)
+    assert len(blocks) == 4 and all(b < K for b in blocks)
+    out_ref, vjp_ref = jax.vjp(ref.gmm_swiglu_ref, x, w)
+    for got, want in zip((out, *grads), (out_ref, *vjp_ref(dout))):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-4)
 
 
 def test_gmm_swiglu_vjp_bf16_vs_fp32_oracle():

@@ -121,8 +121,6 @@ print("FLASH_DECODE_OK")
 
 
 def test_ep_modes_multidevice_subprocess():
-    if not hasattr(jax, "set_mesh") or not hasattr(jax, "shard_map"):
-        pytest.skip("shard_map/set_mesh EP path needs jax >= 0.5")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(

@@ -135,3 +135,48 @@ def test_simulator_ratr_helps_ingress_balance():
     ratr = simulate_unified(
         compile_schedule(build_moe_ffn_forward(cfg), ratr=True), hw)
     assert ratr.makespan_us <= naive.makespan_us * 1.02
+
+
+def test_train_main_depth_cut_sharded_state_and_resume(tmp_path,
+                                                       monkeypatch):
+    """The launcher cuts depth only, builds the state on its shardings, and
+    resumes a compiled step from its own checkpoint."""
+    from repro.launch import train as T
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    argv = ["--arch", "granite-moe-3b-a800m", "--smoke", "--layers", "1",
+            "--mesh", "1x1", "--global-batch", "2", "--seq", "16",
+            "--ckpt-dir", str(tmp_path / "ckpt"),
+            "--ckpt-every", "2"]
+    run = T.main(argv + ["--steps", "3"])
+    assert [m["step"] for m in run.metrics_log] == [1, 2, 3]
+    assert all(np.isfinite(m["loss"]) and np.isfinite(m["grad_norm"])
+               for m in run.metrics_log)
+    smoke = get_smoke_config("granite-moe-3b-a800m")
+    w_in = run.params["blocks"]["moe"]["w_in"]
+    assert w_in.shape == (1, smoke.moe.e_total, smoke.d_model,
+                          2 * smoke.moe.d_expert)
+    for leaf in jax.tree.leaves((run.params, run.opt_state)):
+        assert isinstance(leaf.sharding, jax.sharding.NamedSharding)
+
+    resumed = T.main(argv + ["--steps", "4"])
+    assert resumed.resumed_from == 3
+    assert [m["step"] for m in resumed.metrics_log] == [1, 2, 3, 4]
+    assert resumed.metrics_log[2] == run.metrics_log[2]
+
+
+def test_compile_cache_dir_env_or_fixed_in_checkout(tmp_path, monkeypatch):
+    from repro.launch import compile_cache as CC
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert CC.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert CC.enable_compile_cache() == str(CC.DEFAULT_DIR)
+        repo = CC.DEFAULT_DIR.parent
+        assert (repo / "src" / "repro" / "launch" / "compile_cache.py"
+                ).is_file()
+        ignored = (repo / ".gitignore").read_text().split()
+        assert CC.DEFAULT_DIR.name + "/" in ignored
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
