@@ -8,8 +8,10 @@ Production loop responsibilities, all testable on CPU:
   sequence with no state beyond the step number.
 * **straggler mitigation** — per-step wall-time watchdog with an EWMA
   baseline; steps slower than ``straggler_factor ×`` EWMA are logged and
-  counted. When the step metrics carry ``rank_time_us`` (the dropless step
-  does), a per-rank EWMA accumulates alongside — the observed-time vector
+  counted. When the step metrics carry ``rank_time_us`` (no train step of
+  this package emits it; the steps of ``tests/ftharness.py`` and
+  ``tests/test_elastic.py`` do), a per-rank EWMA accumulates alongside —
+  the observed-time vector
   :meth:`RunState.cost_model` normalizes into ``CostModel(rank_bias=)`` so a
   persistently slow rank becomes the *compile-time* critical rank that
   ``critical_rank_first`` / ``autoselect`` schedule around.
@@ -24,6 +26,18 @@ Production loop responsibilities, all testable on CPU:
   remapped onto the surviving ranks (``core.elastic.remap_plan``) and the
   SSC cache is re-keyed — not flushed — for the new mesh size
   (``SSCCache.rekey_for_mesh``).
+* **spans and counters** — each iteration runs under
+  ``jax.profiler.StepTraceAnnotation("train_step", step_num=step)``, and its
+  phases under ``TraceAnnotation`` spans: ``train_loop/hook``
+  (``inject_fault``), ``train_loop/batch`` (``stream.sharded_batch``),
+  ``train_loop/dispatch`` (``step_fn``), ``train_loop/wait`` (the wait on
+  the loss), ``train_loop/log`` (logging and straggler bookkeeping) and
+  ``train_loop/ckpt`` (save and clean-up). The profiler records them, when
+  it runs, on the clock of the device trace; otherwise each costs about
+  a microsecond of host time. The MoE counters a step's metrics carry
+  (``moe_routed``, ``moe_dropped``, ``moe_slots``) are fetched with the
+  loss, in one transfer, into each ``metrics_log`` entry; no step waits on
+  more than the loss.
 """
 
 from __future__ import annotations
@@ -36,6 +50,12 @@ import jax
 import numpy as np
 
 from repro.checkpoint import ckpt as CK
+
+span = jax.profiler.StepTraceAnnotation
+phase = jax.profiler.TraceAnnotation
+# The train step's MoE counters (``launch/steps.py``), logged where present.
+MOE_COUNTERS = ("moe_routed", "moe_dropped", "moe_slots")
+LOGGED = ("loss", "grad_norm") + MOE_COUNTERS
 
 
 @dataclasses.dataclass
@@ -198,42 +218,53 @@ def train_loop(*, step_fn, params, opt_state, stream, mesh, batch_sharding,
     ewma = None
     step = start_step
     while step < n_steps:
-        if inject_fault is not None:
-            inject_fault(step)  # may raise — simulating a node loss
-        batch = stream.sharded_batch(step, mesh, batch_sharding)
-        t0 = time.time()
-        params, opt_state, metrics = step_fn(params, opt_state, batch)
-        jax.block_until_ready(metrics["loss"])
-        dt = time.time() - t0
+        with span("train_step", step_num=step):
+            if inject_fault is not None:
+                with phase("train_loop/hook"):
+                    inject_fault(step)  # may raise — simulating a node loss
+            with phase("train_loop/batch"):
+                batch = stream.sharded_batch(step, mesh, batch_sharding)
+            t0 = time.time()
+            with phase("train_loop/dispatch"):
+                params, opt_state, metrics = step_fn(params, opt_state,
+                                                     batch)
+            with phase("train_loop/wait"):
+                jax.block_until_ready(metrics["loss"])
+            dt = time.time() - t0
 
-        if ewma is None:
-            ewma = dt
-        elif dt > ft.straggler_factor * ewma:
-            stragglers.append((step, dt, ewma))
-        ewma = (1 - ft.ewma_alpha) * ewma + ft.ewma_alpha * dt
+            with phase("train_loop/log"):
+                if ewma is None:
+                    ewma = dt
+                elif dt > ft.straggler_factor * ewma:
+                    stragglers.append((step, dt, ewma))
+                ewma = (1 - ft.ewma_alpha) * ewma + ft.ewma_alpha * dt
 
-        rt = metrics.get("rank_time_us")
-        if rt is not None:
-            rt = [float(x) for x in np.ravel(np.asarray(rt))]
-            if rank_ewma is None or len(rank_ewma) != len(rt):
-                rank_ewma = rt
-            else:
-                a = ft.ewma_alpha
-                rank_ewma = [(1 - a) * e + a * x
-                             for e, x in zip(rank_ewma, rt)]
+                rt = metrics.get("rank_time_us")
+                if rt is not None:
+                    rt = [float(x) for x in np.ravel(np.asarray(rt))]
+                    if rank_ewma is None or len(rank_ewma) != len(rt):
+                        rank_ewma = rt
+                    else:
+                        a = ft.ewma_alpha
+                        rank_ewma = [(1 - a) * e + a * x
+                                     for e, x in zip(rank_ewma, rt)]
 
-        step += 1
-        if step % log_every == 0 or step == n_steps:
-            metrics_log.append(
-                {"step": step,
-                 "loss": float(metrics["loss"]),
-                 "grad_norm": float(metrics["grad_norm"]),
-                 "step_time_s": dt})
-        if step % ft.ckpt_every == 0 or step == n_steps:
-            CK.save(ft.ckpt_dir, step, (params, opt_state),
-                    extra=_run_extra(elastic, metrics_log, stragglers,
-                                     rank_ewma))
-            CK.gc_old(ft.ckpt_dir, keep=ft.keep)
+                step += 1
+                if step % log_every == 0 or step == n_steps:
+                    got = jax.device_get({k: metrics[k] for k in LOGGED
+                                          if k in metrics})
+                    entry = {"step": step, "loss": float(got["loss"]),
+                             "grad_norm": float(got["grad_norm"]),
+                             "step_time_s": dt}
+                    entry.update((k, int(got[k])) for k in MOE_COUNTERS
+                                 if k in got)
+                    metrics_log.append(entry)
+            if step % ft.ckpt_every == 0 or step == n_steps:
+                with phase("train_loop/ckpt"):
+                    CK.save(ft.ckpt_dir, step, (params, opt_state),
+                            extra=_run_extra(elastic, metrics_log,
+                                             stragglers, rank_ewma))
+                    CK.gc_old(ft.ckpt_dir, keep=ft.keep)
 
     return RunState(step=step, params=params, opt_state=opt_state,
                     metrics_log=metrics_log, stragglers=stragglers,

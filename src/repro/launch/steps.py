@@ -4,6 +4,11 @@
 from ``ShardingRules``; the MoE EP path and the sequence-parallel activation
 constraint are installed via the ambient contexts at *trace* time, keeping
 the model code mesh-agnostic (the paper's low-intrusion integration).
+
+The train step's metrics are ``loss``, ``grad_norm``, ``lr`` and, for a
+fixed-capacity MoE model, the int32 counters ``moe_routed``, ``moe_dropped``
+and ``moe_slots`` (``models.moe.routing_counts``) summed over layers and
+chips.
 """
 
 from __future__ import annotations
@@ -93,16 +98,17 @@ def make_steps(cfg, mesh, *, opt: Optional[adamw.OptConfig] = None,
         def loss_of(p, b):
             with _ctx(b["labels"].shape[0], S), \
                     moe_impl_context(train_moe_impl):
-                return M.loss_fn(cfg, p, b)
+                return M.loss_fn(cfg, p, b, with_counts=True)
 
+        grad_fn = jax.value_and_grad(loss_of, has_aux=True)
         if accum_steps > 1 and B % accum_steps == 0:
             mb = jax.tree.map(
                 lambda a: a.reshape((accum_steps, B // accum_steps)
                                     + a.shape[1:]), batch)
-            lv, grads = adamw.accumulate_grads(
-                lambda p, b: jax.value_and_grad(loss_of)(p, b), params, mb)
+            (lv, counts), grads = adamw.accumulate_grads(grad_fn, params,
+                                                         mb)
         else:
-            lv, grads = jax.value_and_grad(loss_of)(params, batch)
+            (lv, counts), grads = grad_fn(params, batch)
         # Pin gradient shardings to the parameter shardings so the
         # backward-scan accumulators don't materialize unsharded (matters
         # for FSDP expert weights: 21 GB/device without this).
@@ -112,6 +118,10 @@ def make_steps(cfg, mesh, *, opt: Optional[adamw.OptConfig] = None,
         params2, opt_state2, metrics = adamw.apply_updates(
             params, grads, opt_state, opt, grad_transform=grad_transform)
         metrics["loss"] = lv
+        # The MoE layers' counters (moe_routed, moe_dropped, moe_slots),
+        # summed over layers and chips; absent where the MoE path records
+        # none (the dropless path).
+        metrics.update(counts)
         # Surface per-step SSC cache deltas (recompiles this step, hit
         # count, occupancy). Host-side counters only exist eagerly; under
         # jit read ``fns.dropless.cache.info()`` from the training loop.

@@ -229,6 +229,7 @@ def init_attention(key, d_model, n_heads, n_kv_heads, head_dim,
     return p
 
 
+@jax.named_scope("attention")
 def attention(p, x, *, n_heads, n_kv_heads, head_dim, rope_theta,
               causal=True, sliding_window=0, block=1024,
               cache=None, positions=None):

@@ -9,6 +9,12 @@ parameters (compact HLO, remat-friendly); the hybrid stack scans over
 The MoE block takes a pluggable ``moe_impl`` so the distributed launcher can
 inject the EP-sharded execution path (see ``repro/parallel/ep.py``) without
 touching model code — the paper's "low code intrusion" integration point.
+
+The embedding and the unembedding with its cross-entropy run under the
+``embed`` and ``unembed_ce`` named scopes. The MoE layer's counters (see
+``models.moe.routing_counts``) leave the layer scan as its outputs, summed
+over layers: ``loss_fn(..., with_counts=True)`` returns them beside the
+loss.
 """
 
 from __future__ import annotations
@@ -21,7 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.parallel.ctx import constrain_activation, current_moe_impl
+from repro.parallel.ctx import (constrain_activation, current_moe_impl,
+                                moe_counts_scope)
 
 from . import layers as L
 from .moe import MoEConfig, init_moe, moe_grouped
@@ -257,6 +264,7 @@ def block_apply(cfg: ModelConfig, btype: str, p, x, cache=None,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("embed")
 def embed_inputs(cfg: ModelConfig, params, batch) -> jnp.ndarray:
     dt = cfg.compute_dtype
     if cfg.family == "audio":
@@ -271,9 +279,18 @@ def embed_inputs(cfg: ModelConfig, params, batch) -> jnp.ndarray:
     return x
 
 
+def _sum_counts(counts: list) -> dict:
+    """Key-wise sum of MoE counter dicts ({} for none)."""
+    return {k: sum(c[k] for c in counts) for k in counts[0]} if counts else {}
+
+
 def _run_stack(cfg: ModelConfig, params, x, caches=None,
                moe_impl=None):
-    """Apply all layers. caches: stacked pytree or None."""
+    """Apply all layers. caches: stacked pytree or None.
+
+    Returns ``(x, new_caches, counts)``: ``counts`` holds the MoE layers'
+    counters summed over layers, ``{}`` where no layer recorded any (the
+    hybrid stacks run no MoE layer)."""
     types = cfg.layer_types()
 
     if cfg.family == "hybrid":
@@ -313,16 +330,17 @@ def _run_stack(cfg: ModelConfig, params, x, caches=None,
             new_tail.append(nc)
         new_caches = (None if caches is None
                       else {"super": new_sup, "tail": new_tail})
-        return x, new_caches
+        return x, new_caches, {}
 
     btype = types[0]
 
     def body(x, inp):
         ps, cs = inp
-        x, nc = block_apply(cfg, btype, ps, x, cs, moe_impl)
+        with moe_counts_scope() as counts:
+            x, nc = block_apply(cfg, btype, ps, x, cs, moe_impl)
         # Sequence-parallel residual stream between blocks (no-op unless an
         # activation_sharding context is active — keeps model mesh-agnostic).
-        return constrain_activation(x), nc
+        return constrain_activation(x), (nc, _sum_counts(counts))
 
     if cfg.remat and cfg.remat_policy == "save_moe":
         fn = jax.checkpoint(
@@ -333,25 +351,28 @@ def _run_stack(cfg: ModelConfig, params, x, caches=None,
     else:
         fn = body
     if cfg.scan_layers:
-        x, new_caches = jax.lax.scan(fn, x, (params["blocks"], caches))
-        return x, new_caches
+        x, (new_caches, counts) = jax.lax.scan(
+            fn, x, (params["blocks"], caches))
+        return x, new_caches, {k: jnp.sum(v) for k, v in counts.items()}
     # Unrolled loop (used by the dry-run cost probes: XLA's HloCostAnalysis
     # counts while bodies once, so scanned stacks under-report flops).
-    ncs = []
+    ncs, counts = [], []
     for i in range(cfg.n_layers):
         ps = jax.tree.map(lambda a: a[i], params["blocks"])
         cs = (None if caches is None
               else jax.tree.map(lambda a: a[i], caches))
-        x, nc = fn(x, (ps, cs))
+        x, (nc, c) = fn(x, (ps, cs))
         ncs.append(nc)
+        counts.append(c)
     new_caches = (None if caches is None
                   else jax.tree.map(lambda *a: jnp.stack(a), *ncs))
-    return x, new_caches
+    return x, new_caches, {k: jnp.sum(v)
+                           for k, v in _sum_counts(counts).items()}
 
 
 def forward(cfg: ModelConfig, params, batch, moe_impl=None):
     """Full forward → logits [B, S, Vp] (VLM: token region only)."""
-    x = final_hidden(cfg, params, batch, moe_impl)
+    x, _ = final_hidden(cfg, params, batch, moe_impl)
     unembed = (params["embed"].T if cfg.tie_embeddings
                else params["unembed"])
     return jnp.einsum("bsd,dv->bsv", x, unembed.astype(x.dtype))
@@ -371,13 +392,15 @@ def _ce_chunk(cfg: ModelConfig, x, labels, unembed):
 
 
 def loss_fn(cfg: ModelConfig, params, batch, moe_impl=None,
-            ce_chunk: int = 512):
+            ce_chunk: int = 512, with_counts: bool = False):
     """Next-token (or frame-label) cross entropy, fp32, vocab-pad masked.
 
     The unembedding + logsumexp run in sequence chunks under jax.checkpoint
     so the full [B, S, V] logits tensor never materializes — required to fit
-    the 100k+-vocab archs in HBM at train_4k."""
-    x = final_hidden(cfg, params, batch, moe_impl)
+    the 100k+-vocab archs in HBM at train_4k. With ``with_counts`` returns
+    ``(loss, counts)``: the MoE layers' counters summed over layers (``{}``
+    for a model with no MoE layer)."""
+    x, counts = final_hidden(cfg, params, batch, moe_impl)
     labels = batch["labels"]
     unembed = (params["embed"].T if cfg.tie_embeddings
                else params["unembed"])
@@ -385,30 +408,33 @@ def loss_fn(cfg: ModelConfig, params, batch, moe_impl=None,
     n = max(1, S // max(1, min(ce_chunk, S)))
     while S % n:
         n -= 1
-    xs = x.reshape(B, n, S // n, -1).transpose(1, 0, 2, 3)
-    ls = labels.reshape(B, n, S // n).transpose(1, 0, 2)
 
     def chunk_body(carry, inp):
         x_c, l_c = inp
         nll_c, cnt_c = _ce_chunk(cfg, x_c, l_c, unembed)
         return (carry[0] + nll_c, carry[1] + cnt_c), None
 
-    (nll, cnt), _ = jax.lax.scan(jax.checkpoint(chunk_body),
-                                 (0.0, 0.0), (xs, ls))
-    return nll / jnp.maximum(cnt, 1.0)
+    with jax.named_scope("unembed_ce"):
+        xs = x.reshape(B, n, S // n, -1).transpose(1, 0, 2, 3)
+        ls = labels.reshape(B, n, S // n).transpose(1, 0, 2)
+        (nll, cnt), _ = jax.lax.scan(jax.checkpoint(chunk_body),
+                                     (0.0, 0.0), (xs, ls))
+        loss = nll / jnp.maximum(cnt, 1.0)
+    return (loss, counts) if with_counts else loss
 
 
 def final_hidden(cfg: ModelConfig, params, batch, moe_impl=None):
-    """Forward to the final (pre-unembedding) hidden states."""
+    """Forward to the final (pre-unembedding) hidden states; returns
+    ``(hidden, counts)``, ``counts`` as :func:`loss_fn` gives them."""
     x = constrain_activation(embed_inputs(cfg, params, batch))
-    x, _ = _run_stack(cfg, params, x, None, moe_impl)
+    x, _, counts = _run_stack(cfg, params, x, None, moe_impl)
     if cfg.norm != "nonparam_ln":
         x = L.apply_norm(cfg.norm, x, params, "ln_f")
     else:
         x = L.nonparam_ln(x)
     if cfg.family == "vlm" and "patches" in batch:
         x = x[:, batch["patches"].shape[1]:]
-    return x
+    return x, counts
 
 
 # ---------------------------------------------------------------------------
@@ -467,7 +493,7 @@ def decode_step(cfg: ModelConfig, params, token, cache, moe_impl=None):
     x = params["embed"].astype(cfg.compute_dtype)[token]
     if cfg.embed_scale:
         x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
-    x, new_cache = _run_stack(cfg, params, x, cache, moe_impl)
+    x, new_cache, _ = _run_stack(cfg, params, x, cache, moe_impl)
     if cfg.norm != "nonparam_ln":
         x = L.apply_norm(cfg.norm, x, params, "ln_f")
     else:
@@ -488,7 +514,7 @@ def prefill(cfg: ModelConfig, params, batch, max_len: int, moe_impl=None):
     B, S = tokens.shape
     cache = init_cache(cfg, B, max_len)
     x = embed_inputs(cfg, params, batch)
-    x, new_cache = _run_stack(cfg, params, x, cache, moe_impl)
+    x, new_cache, _ = _run_stack(cfg, params, x, cache, moe_impl)
     if cfg.norm != "nonparam_ln":
         x = L.apply_norm(cfg.norm, x, params, "ln_f")
     else:

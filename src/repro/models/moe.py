@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.parallel.ctx import record_moe_counts
+
 from .layers import glu_act
 
 
@@ -100,6 +102,15 @@ def capacity(tokens: int, mc: MoEConfig, ep: int = 1) -> int:
     all-to-all chunks stay uniform."""
     c = int(np.ceil(tokens * mc.top_k / mc.e_total * mc.capacity_factor))
     return max(ep, ((c + ep - 1) // ep) * ep)
+
+
+def routing_counts(slot, C: int, slots: int) -> dict:
+    """One MoE layer's counters: top-k choices made (``moe_routed``), those
+    capacity dropped (``moe_dropped``, slot ``>= C``) and rows of expert
+    work issued (``moe_slots``), as int32 scalars."""
+    return {"moe_routed": jnp.int32(slot.size),
+            "moe_dropped": jnp.sum(slot >= C, dtype=jnp.int32),
+            "moe_slots": jnp.int32(slots)}
 
 
 def expert_ffn(w_in, w_down, x, act: str = "swiglu"):
@@ -438,24 +449,31 @@ def moe_grouped(params, x, mc: MoEConfig, act: str = "swiglu",
     xt = x.reshape(T, d)
     E = mc.e_total
     C = cap or capacity(T, mc)
-    top_p, top_i, slot = _routed(params, xt, mc, C)
+    with jax.named_scope("moe/router"):
+        top_p, top_i = router_topk(params["router"], xt, mc)
 
     # Dispatch: scatter tokens into [E, C, d] expert buffers.
-    disp = jnp.zeros((E, C + 1, d), x.dtype)
-    tok_idx = jnp.broadcast_to(jnp.arange(T)[:, None], top_i.shape)
-    disp = disp.at[top_i.reshape(-1), slot.reshape(-1)].add(
-        xt[tok_idx.reshape(-1)])
-    disp = disp[:, :C]
+    with jax.named_scope("moe/dispatch"):
+        top_p, top_i, slot = make_dispatch(top_p, top_i, T, E, C)
+        disp = jnp.zeros((E, C + 1, d), x.dtype)
+        tok_idx = jnp.broadcast_to(jnp.arange(T)[:, None], top_i.shape)
+        disp = disp.at[top_i.reshape(-1), slot.reshape(-1)].add(
+            xt[tok_idx.reshape(-1)])
+        disp = disp[:, :C]
 
-    if gmm_fn is not None:
-        out_e = gmm_fn(disp, params["w_in"], params["w_down"], act)
-    else:
-        out_e = expert_ffn(params["w_in"], params["w_down"], disp, act)
+    with jax.named_scope("moe/expert_ffn"):
+        if gmm_fn is not None:
+            out_e = gmm_fn(disp, params["w_in"], params["w_down"], act)
+        else:
+            out_e = expert_ffn(params["w_in"], params["w_down"], disp, act)
 
     # Combine: gather back with routing weights.
-    out_e = jnp.concatenate([out_e, jnp.zeros_like(out_e[:, :1])], axis=1)
-    y = jnp.zeros((T, d), x.dtype)
-    for j in range(mc.top_k):
-        y = y + (out_e[top_i[:, j], slot[:, j]]
-                 * top_p[:, j][:, None].astype(x.dtype))
+    with jax.named_scope("moe/combine"):
+        out_e = jnp.concatenate([out_e, jnp.zeros_like(out_e[:, :1])],
+                                axis=1)
+        y = jnp.zeros((T, d), x.dtype)
+        for j in range(mc.top_k):
+            y = y + (out_e[top_i[:, j], slot[:, j]]
+                     * top_p[:, j][:, None].astype(x.dtype))
+    record_moe_counts(routing_counts(slot, C, E * C))
     return y.reshape(B, S, d)

@@ -69,10 +69,13 @@ def clip_by_global_norm(grads, max_norm):
     return jax.tree.map(lambda g: g * scale.astype(g.dtype), grads), norm
 
 
+@jax.named_scope("optimizer")
 def apply_updates(params, grads, state, oc: OptConfig,
                   grad_transform: Optional[Callable] = None):
     """One AdamW step on the fp32 masters; returns the refreshed compute
-    (bf16) params. Returns (new_params, new_state, metrics)."""
+    (bf16) params. Returns (new_params, new_state, metrics). The whole
+    update, clip and global norm included, runs under the ``optimizer``
+    named scope."""
     if grad_transform is not None:
         grads = grad_transform(grads)
     grads, gnorm = clip_by_global_norm(grads, oc.clip_norm)
@@ -111,13 +114,20 @@ def accumulate_grads(loss_and_grad_fn, params, microbatches):
     """Microbatch gradient accumulation via lax.scan (fixed microbatch dim).
 
     ``microbatches``: pytree with leading [n_micro, ...] dims.
+    ``loss_and_grad_fn(params, mb)`` returns ``((loss, aux), grads)``, as
+    ``jax.value_and_grad(..., has_aux=True)`` does. Returns ``((mean loss,
+    aux summed over microbatches), mean grads)``.
     """
     def body(acc, mb):
-        loss, grads = loss_and_grad_fn(params, mb)
-        acc_g, acc_l = acc
-        return (jax.tree.map(jnp.add, acc_g, grads), acc_l + loss), None
+        out, grads = loss_and_grad_fn(params, mb)
+        acc_g, acc_out = acc
+        return (jax.tree.map(jnp.add, acc_g, grads),
+                jax.tree.map(jnp.add, acc_out, out)), None
 
     zero = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    (g, l), _ = jax.lax.scan(body, (zero, 0.0), microbatches)  # noqa: E741
+    first = jax.tree.map(lambda a: a[0], microbatches)
+    out0 = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), jax.eval_shape(
+        lambda: loss_and_grad_fn(params, first)[0]))
+    (g, (loss, aux)), _ = jax.lax.scan(body, (zero, out0), microbatches)
     n = jax.tree.leaves(microbatches)[0].shape[0]
-    return l / n, jax.tree.map(lambda x: x / n, g)
+    return (loss / n, aux), jax.tree.map(lambda x: x / n, g)

@@ -77,3 +77,27 @@ def moe_impl_context(impl):
 
 def current_moe_impl():
     return getattr(_CTX, "moe_impl", None)
+
+
+@contextlib.contextmanager
+def moe_counts_scope():
+    """Collect the MoE counters recorded while tracing under this context.
+
+    Yields the list that :func:`record_moe_counts` appends to. Open it at
+    the trace level of the MoE layer's caller (a scan body over the layers),
+    so that the counters can leave as that body's outputs."""
+    prev = getattr(_CTX, "moe_counts", None)
+    got: list = []
+    _CTX.moe_counts = got
+    try:
+        yield got
+    finally:
+        _CTX.moe_counts = prev
+
+
+def record_moe_counts(counts: dict):
+    """Hand one MoE layer's counters to the innermost open
+    :func:`moe_counts_scope`; without one they are dropped."""
+    sink = getattr(_CTX, "moe_counts", None)
+    if sink is not None:
+        sink.append(counts)

@@ -18,6 +18,13 @@ Routing uses per-(destination, expert) fixed capacity so all comm shapes are
 static. Every device routes its local tokens with the replicated router;
 combine applies top-k weights back at the source — exactly the paper's
 Dispatch→…→Combine boundary.
+
+Every stage runs under a ``jax.named_scope`` (``moe/router``,
+``moe/dispatch``, ``moe/exchange``, ``moe/expert_ffn``, ``moe/combine``), so
+each device op, forward, transposed or recomputed, names its stage in its
+HLO ``op_name``. Each call hands ``ctx.record_moe_counts`` the layer's
+counters (``models.moe.routing_counts``) as per-device vectors: each chip
+counts its own top-k choices, capacity drops and expert rows issued.
 """
 
 from __future__ import annotations
@@ -30,8 +37,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.models.moe import MoEConfig, router_topk
+from repro.models.moe import MoEConfig, router_topk, routing_counts
 from repro.models.layers import glu_act
+from repro.parallel.ctx import record_moe_counts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,6 +140,7 @@ def ring_chunk_caps(plan, ep: int, topology=None, bucket=None,
     return tuple(out)
 
 
+@jax.named_scope("moe/expert_ffn")
 def _expert_ffn_local(w_in, w_down, x, act, use_pallas):
     if use_pallas:
         from repro.kernels.ops import moe_expert_ffn
@@ -149,23 +158,26 @@ def _dispatch_buffers(x2d, router, mc: MoEConfig, ep: int, C: int):
     """
     T, d = x2d.shape
     e_loc = mc.e_total // ep
-    top_p, top_i = router_topk(router, x2d, mc)
-    flat_e = top_i.reshape(-1)
-    onehot = jax.nn.one_hot(flat_e, mc.e_total, dtype=jnp.int32)
-    pos = jnp.cumsum(onehot, axis=0) - 1
-    slot = jnp.take_along_axis(pos, flat_e[:, None], axis=1)[:, 0]
-    keep = slot < C
-    slot = jnp.where(keep, slot, C)
-    top_p = top_p * keep.reshape(top_p.shape)
+    with jax.named_scope("moe/router"):
+        top_p, top_i = router_topk(router, x2d, mc)
+    with jax.named_scope("moe/dispatch"):
+        flat_e = top_i.reshape(-1)
+        onehot = jax.nn.one_hot(flat_e, mc.e_total, dtype=jnp.int32)
+        pos = jnp.cumsum(onehot, axis=0) - 1
+        slot = jnp.take_along_axis(pos, flat_e[:, None], axis=1)[:, 0]
+        keep = slot < C
+        slot = jnp.where(keep, slot, C)
+        top_p = top_p * keep.reshape(top_p.shape)
 
-    send = jnp.zeros((mc.e_total, C + 1, d), x2d.dtype)
-    tok_idx = jnp.broadcast_to(jnp.arange(T)[:, None], top_i.shape)
-    send = send.at[flat_e, slot.reshape(-1)].add(
-        x2d[tok_idx.reshape(-1)])
-    send = send[:, :C].reshape(ep, e_loc, C, d)
+        send = jnp.zeros((mc.e_total, C + 1, d), x2d.dtype)
+        tok_idx = jnp.broadcast_to(jnp.arange(T)[:, None], top_i.shape)
+        send = send.at[flat_e, slot.reshape(-1)].add(
+            x2d[tok_idx.reshape(-1)])
+        send = send[:, :C].reshape(ep, e_loc, C, d)
     return send, top_p, top_i, slot.reshape(top_i.shape)
 
 
+@jax.named_scope("moe/combine")
 def _combine(back, top_p, top_i, slot, T, d, ep, e_loc, C, dtype):
     """back: [ep(dst), e_loc, C, d] results at their send slots → [T, d]."""
     flat = jnp.concatenate(
@@ -241,10 +253,14 @@ def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
             x_spec = P(dp if B > 1 else None,
                        epc.axis if S % ep == 0 and S > 1 else None, None)
 
+        # Each device's counters leave the shard as its own entry of a
+        # vector over every mesh axis.
+        per_device = P(tuple(mesh.axis_names))
+
         @partial(jax.shard_map, mesh=mesh,
                  in_specs=(P(None, None), P(epc.axis, None, None),
                            P(epc.axis, None, None), x_spec),
-                 out_specs=x_spec, check_vma=False)
+                 out_specs=(x_spec, per_device), check_vma=False)
         def run(router, w_in, w_down, x_loc):
             b, s, _ = x_loc.shape
             T = b * s
@@ -254,23 +270,32 @@ def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
                 x2d, router, mc, ep, C)
 
             if epc.mode == "baseline":
-                recv = jax.lax.all_to_all(send, epc.axis, split_axis=0,
-                                          concat_axis=0, tiled=True)
-                xin = recv.transpose(1, 0, 2, 3).reshape(e_loc, ep * C, d)
+                with jax.named_scope("moe/exchange"):
+                    recv = jax.lax.all_to_all(send, epc.axis, split_axis=0,
+                                              concat_axis=0, tiled=True)
+                    xin = recv.transpose(1, 0, 2, 3).reshape(
+                        e_loc, ep * C, d)
                 y = _expert_ffn_local(w_in, w_down, xin, act,
                                       epc.use_pallas)
-                y = y.reshape(e_loc, ep, C, d).transpose(1, 0, 2, 3)
-                back = jax.lax.all_to_all(y, epc.axis, split_axis=0,
-                                          concat_axis=0, tiled=True)
+                with jax.named_scope("moe/exchange"):
+                    y = y.reshape(e_loc, ep, C, d).transpose(1, 0, 2, 3)
+                    back = jax.lax.all_to_all(y, epc.axis, split_axis=0,
+                                              concat_axis=0, tiled=True)
+                rows = e_loc * ep * C
             else:
-                back = _hyperparallel_ring(
+                back, rows = _hyperparallel_ring(
                     send, w_in, w_down, act, ep, epc)
 
             y = _combine(back, top_p, top_i, slot, T, d, ep, e_loc, C,
                          x_loc.dtype)
-            return y.reshape(b, s, d)
+            counts = {k: v[None] for k, v in
+                      routing_counts(slot, C, rows).items()}
+            return y.reshape(b, s, d), counts
 
-        return run(params["router"], params["w_in"], params["w_down"], x)
+        y, counts = run(params["router"], params["w_in"],
+                        params["w_down"], x)
+        record_moe_counts(counts)
+        return y
 
     def _hyperparallel_ring(send, w_in, w_down, act, ep, epc):
         """RATR ring: step k moves the chunk for destination (r+k) and the
@@ -282,10 +307,14 @@ def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
         sliced to ``min(C, caps[k])`` rows per (dst, expert) slot — tokens
         always occupy the head of each slot, so the sliced rows are exactly
         the routed ones — and all-padding steps (cap 0) are skipped.
+
+        Returns ``(back, rows)``: the results, and the expert rows this
+        device's FFN was issued over all steps.
         """
         r = jax.lax.axis_index(epc.axis)
         e_loc, C, d = send.shape[1], send.shape[2], send.shape[3]
         back = jnp.zeros_like(send)
+        rows = 0
 
         def step_cap(k):
             return C if ring_caps is None else min(C, ring_caps[k])
@@ -293,9 +322,13 @@ def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
         # k = 0: local chunk.
         c0 = step_cap(0)
         if c0 > 0:
-            chunk0 = jnp.take(send, r, axis=0)[:, :c0]   # dyn [e_loc,c0,d]
+            with jax.named_scope("moe/dispatch"):
+                chunk0 = jnp.take(send, r, axis=0)[:, :c0]  # [e_loc,c0,d]
             y0 = _expert_ffn_local(w_in, w_down, chunk0, act, epc.use_pallas)
-            back = jax.lax.dynamic_update_slice(back, y0[None], (r, 0, 0, 0))
+            with jax.named_scope("moe/combine"):
+                back = jax.lax.dynamic_update_slice(back, y0[None],
+                                                    (r, 0, 0, 0))
+            rows += e_loc * c0
 
         for k in range(1, ep):
             ck = step_cap(k)
@@ -304,13 +337,18 @@ def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
             perm_fwd = [(i, (i + k) % ep) for i in range(ep)]
             perm_bwd = [(i, (i - k) % ep) for i in range(ep)]
             # RATR: source r's step-k chunk targets destination (r+k).
-            chunk = jnp.take(send, (r + k) % ep, axis=0)[:, :ck]
-            arrived = jax.lax.ppermute(chunk, epc.axis, perm_fwd)
+            with jax.named_scope("moe/dispatch"):
+                chunk = jnp.take(send, (r + k) % ep, axis=0)[:, :ck]
+            with jax.named_scope("moe/exchange"):
+                arrived = jax.lax.ppermute(chunk, epc.axis, perm_fwd)
             y = _expert_ffn_local(w_in, w_down, arrived, act,
                                   epc.use_pallas)
-            returned = jax.lax.ppermute(y, epc.axis, perm_bwd)
-            back = jax.lax.dynamic_update_slice(
-                back, returned[None], ((r + k) % ep, 0, 0, 0))
-        return back
+            with jax.named_scope("moe/exchange"):
+                returned = jax.lax.ppermute(y, epc.axis, perm_bwd)
+            with jax.named_scope("moe/combine"):
+                back = jax.lax.dynamic_update_slice(
+                    back, returned[None], ((r + k) % ep, 0, 0, 0))
+            rows += e_loc * ck
+        return back, rows
 
     return moe_impl

@@ -89,8 +89,11 @@ def test_accumulate_grads_matches_full_batch():
 
     full = jax.grad(loss)(w, xs)
     mb = xs.reshape(4, 2, 2)
-    _, acc = adamw.accumulate_grads(
-        lambda p, b: jax.value_and_grad(loss)(p, b), w, mb)
+    (_, aux), acc = adamw.accumulate_grads(
+        lambda p, b: jax.value_and_grad(
+            lambda p, b: (loss(p, b), {"rows": b.shape[0]}),
+            has_aux=True)(p, b), w, mb)
+    assert int(aux["rows"]) == 8
     np.testing.assert_allclose(np.asarray(acc), np.asarray(full),
                                rtol=1e-5, atol=1e-6)
 
