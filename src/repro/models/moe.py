@@ -6,6 +6,9 @@ Three execution paths, all numerically equivalent (tests assert it):
 * ``moe_grouped``  — capacity-based dispatch/combine with sorted token
   buffers feeding a grouped GEMM (optionally the Pallas kernel); this is the
   single-device analogue of the paper's Dispatch→GMM→SwiGLU→GMM→Combine.
+  Rows enter and leave the ``[E, C, d]`` buffer by gathers alone, forward
+  and backward (``dispatch_rows``, ``combine_rows``; the EP path uses the
+  same pair).
 * EP-sharded execution lives in ``repro/parallel/ep.py`` (shard_map): the
   ``baseline`` mode uses a collective AllToAll, the ``hyperparallel`` mode
   the RATR chunked-ppermute schedule mirroring the paper's one-sided tasks.
@@ -23,7 +26,8 @@ are dropped (standard practice; the dense ref applies the same mask).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from functools import partial
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -120,20 +124,128 @@ def expert_ffn(w_in, w_down, x, act: str = "swiglu"):
     return jnp.einsum("ecf,efd->ecd", h, w_down.astype(x.dtype))
 
 
-def make_dispatch(top_p, top_i, T: int, E: int, C: int):
-    """Position-in-expert assignment under fixed capacity.
+class SlotMap(NamedTuple):
+    """Where each top-k choice sits in the ``[E, C]`` capacity buffer.
 
-    Returns (combine_w [T,k], slot [T,k] in [0, C) or C for dropped).
+    ``slot`` [T, k]: position within the expert, ``C`` where dropped.
+    ``keep`` [T, k]: the choice holds a slot.
+    ``dest`` [T, k]: flat slot ``e * C + slot``, clamped where dropped.
+    ``src`` [E, C]: flat choice ``t * k + j`` filling each slot, ``T * k``
+    where the slot is empty — the inverse of ``dest``.
     """
-    k = top_i.shape[1]
+
+    slot: jax.Array
+    keep: jax.Array
+    dest: jax.Array
+    src: jax.Array
+
+
+def slot_map(top_i, E: int, C: int) -> SlotMap:
+    """Capacity slots of the choices ``top_i`` [T, k]: a choice's slot is
+    the running count of its expert in token order, kept iff below ``C``."""
+    T, k = top_i.shape
+    n = T * k
     flat_e = top_i.reshape(-1)                                  # [T*k]
-    # position of each (token, choice) within its expert, in token order
     onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)         # [T*k, E]
     pos = jnp.cumsum(onehot, axis=0) - 1                        # running idx
     slot = jnp.take_along_axis(pos, flat_e[:, None], axis=1)[:, 0]
     keep = slot < C
-    return (top_p * keep.reshape(T, k)), flat_e.reshape(T, k), \
-        jnp.where(keep, slot, C).reshape(T, k)
+    # The inverse without a scatter: a stable sort by expert lists each
+    # expert's choices in token order, so expert e's c-th choice sits at
+    # its group's offset + c.
+    count = pos[-1] + 1                                         # [E]
+    offset = jnp.cumsum(count) - count
+    order = jnp.argsort(flat_e, stable=True)
+    c = jnp.arange(C)
+    filled = c[None, :] < jnp.minimum(count, C)[:, None]
+    src = jnp.where(filled,
+                    order[jnp.minimum(offset[:, None] + c, n - 1)], n)
+    dest = flat_e * C + jnp.minimum(slot, C - 1)
+    return SlotMap(jnp.where(keep, slot, C).reshape(T, k),
+                   keep.reshape(T, k), dest.reshape(T, k), src)
+
+
+def make_dispatch(top_p, top_i, E: int, C: int):
+    """Position-in-expert assignment under fixed capacity.
+
+    Returns (combine_w [T,k], top_i [T,k], slot [T,k] in [0, C) or C for
+    dropped).
+    """
+    sm = slot_map(top_i, E, C)
+    return top_p * sm.keep, top_i, sm.slot
+
+
+# Rows move between tokens and the capacity buffer only by gathers, in both
+# directions: the slots form a permutation of the kept choices, so the
+# transpose of each gather is a gather through the inverse map.
+
+def _token_rows(x2d, src, k: int):
+    """x2d [T, d] rows of the tokens whose choices fill ``src``'s slots;
+    zero rows where a slot is empty."""
+    T = x2d.shape[0]
+    rows = x2d[jnp.minimum(src // k, T - 1)]
+    return jnp.where((src < T * k)[..., None], rows,
+                     jnp.zeros((), x2d.dtype))
+
+
+def _slot_rows(buf, dest, keep):
+    """buf [E, C, d] rows at the choices' slots [T, k, d]; zero where
+    dropped."""
+    rows = buf.reshape(-1, buf.shape[-1])[dest]
+    return jnp.where(keep[..., None], rows, jnp.zeros((), buf.dtype))
+
+
+@jax.custom_vjp
+def dispatch_rows(x2d, sm: SlotMap):
+    """Tokens [T, d] → capacity buffer [E, C, d]: slot (e, c) holds the
+    token of choice ``sm.src[e, c]``, zeros where empty."""
+    return _token_rows(x2d, sm.src, sm.dest.shape[1])
+
+
+def _dispatch_fwd(x2d, sm):
+    return dispatch_rows(x2d, sm), sm
+
+
+def _dispatch_bwd(sm, g):
+    rows = _slot_rows(g, sm.dest, sm.keep)
+    # Summed choice by choice in the activation dtype, the order in which a
+    # scatter-add of the k copies accumulates them.
+    dx = rows[:, 0]
+    for j in range(1, rows.shape[1]):
+        dx = dx + rows[:, j]
+    return dx, None
+
+
+dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine_rows(buf, top_p, sm: SlotMap):
+    """Expert outputs [E, C, d] → tokens [T, d]:
+    ``y[t] = Σ_j top_p[t, j] · buf[sm.dest[t, j]]`` over the kept choices."""
+    return _combine_fwd(buf, top_p, sm)[0]
+
+
+def _weighted_sum(rows, p):
+    return jnp.einsum("tkd,tk->td", rows, p)
+
+
+def _combine_fwd(buf, top_p, sm):
+    rows = _slot_rows(buf, sm.dest, sm.keep)
+    return _weighted_sum(rows, top_p.astype(buf.dtype)), (rows, top_p, sm.src)
+
+
+def _combine_bwd(res, dy):
+    rows, top_p, src = res
+    n = top_p.size
+    p = top_p.reshape(-1)[jnp.minimum(src, n - 1)].astype(dy.dtype)
+    d_buf = _token_rows(dy, src, rows.shape[1]) * p[..., None]
+    # top_p's gradient is the forward einsum's own transpose, in its dtype.
+    _, vjp = jax.vjp(partial(_weighted_sum, rows), top_p.astype(rows.dtype))
+    return d_buf, vjp(dy)[0].astype(top_p.dtype), None
+
+
+combine_rows.defvjp(_combine_fwd, _combine_bwd)
 
 
 def moe_dense_ref(params, x, mc: MoEConfig, act: str = "swiglu",
@@ -158,8 +270,7 @@ def moe_dense_ref(params, x, mc: MoEConfig, act: str = "swiglu",
 
 def _routed(params, xt, mc: MoEConfig, C: int):
     top_p, top_i = router_topk(params["router"], xt, mc)
-    top_p, top_i, slot = make_dispatch(top_p, top_i, xt.shape[0],
-                                       mc.e_total, C)
+    top_p, top_i, slot = make_dispatch(top_p, top_i, mc.e_total, C)
     return top_p, top_i, slot
 
 
@@ -452,14 +563,10 @@ def moe_grouped(params, x, mc: MoEConfig, act: str = "swiglu",
     with jax.named_scope("moe/router"):
         top_p, top_i = router_topk(params["router"], xt, mc)
 
-    # Dispatch: scatter tokens into [E, C, d] expert buffers.
     with jax.named_scope("moe/dispatch"):
-        top_p, top_i, slot = make_dispatch(top_p, top_i, T, E, C)
-        disp = jnp.zeros((E, C + 1, d), x.dtype)
-        tok_idx = jnp.broadcast_to(jnp.arange(T)[:, None], top_i.shape)
-        disp = disp.at[top_i.reshape(-1), slot.reshape(-1)].add(
-            xt[tok_idx.reshape(-1)])
-        disp = disp[:, :C]
+        sm = slot_map(top_i, E, C)
+        top_p = top_p * sm.keep
+        disp = dispatch_rows(xt, sm)
 
     with jax.named_scope("moe/expert_ffn"):
         if gmm_fn is not None:
@@ -467,13 +574,7 @@ def moe_grouped(params, x, mc: MoEConfig, act: str = "swiglu",
         else:
             out_e = expert_ffn(params["w_in"], params["w_down"], disp, act)
 
-    # Combine: gather back with routing weights.
     with jax.named_scope("moe/combine"):
-        out_e = jnp.concatenate([out_e, jnp.zeros_like(out_e[:, :1])],
-                                axis=1)
-        y = jnp.zeros((T, d), x.dtype)
-        for j in range(mc.top_k):
-            y = y + (out_e[top_i[:, j], slot[:, j]]
-                     * top_p[:, j][:, None].astype(x.dtype))
-    record_moe_counts(routing_counts(slot, C, E * C))
-    return y.reshape(B, S, d)
+        y = combine_rows(out_e, top_p, sm)
+    record_moe_counts(routing_counts(sm.slot, C, E * C))
+    return y.astype(x.dtype).reshape(B, S, d)

@@ -37,7 +37,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.models.moe import MoEConfig, router_topk, routing_counts
+from repro.models.moe import (MoEConfig, combine_rows, dispatch_rows,
+                              router_topk, routing_counts, slot_map)
 from repro.models.layers import glu_act
 from repro.parallel.ctx import record_moe_counts
 
@@ -151,44 +152,19 @@ def _expert_ffn_local(w_in, w_down, x, act, use_pallas):
 
 
 def _dispatch_buffers(x2d, router, mc: MoEConfig, ep: int, C: int):
-    """Local routing + scatter into the per-(dst, expert) send buffer.
+    """Local routing + gather into the per-(dst, expert) send buffer.
 
-    Returns (send [ep, e_loc, C, d], top_p, top_i, slot) where slot is the
-    position within the (dst, expert) capacity bucket (C = dropped).
+    Returns (send [ep, e_loc, C, d], top_p, sm): ``sm`` is the choices'
+    :class:`SlotMap` over the global experts' (dst, expert) buckets.
     """
-    T, d = x2d.shape
-    e_loc = mc.e_total // ep
+    d = x2d.shape[1]
     with jax.named_scope("moe/router"):
         top_p, top_i = router_topk(router, x2d, mc)
     with jax.named_scope("moe/dispatch"):
-        flat_e = top_i.reshape(-1)
-        onehot = jax.nn.one_hot(flat_e, mc.e_total, dtype=jnp.int32)
-        pos = jnp.cumsum(onehot, axis=0) - 1
-        slot = jnp.take_along_axis(pos, flat_e[:, None], axis=1)[:, 0]
-        keep = slot < C
-        slot = jnp.where(keep, slot, C)
-        top_p = top_p * keep.reshape(top_p.shape)
-
-        send = jnp.zeros((mc.e_total, C + 1, d), x2d.dtype)
-        tok_idx = jnp.broadcast_to(jnp.arange(T)[:, None], top_i.shape)
-        send = send.at[flat_e, slot.reshape(-1)].add(
-            x2d[tok_idx.reshape(-1)])
-        send = send[:, :C].reshape(ep, e_loc, C, d)
-    return send, top_p, top_i, slot.reshape(top_i.shape)
-
-
-@jax.named_scope("moe/combine")
-def _combine(back, top_p, top_i, slot, T, d, ep, e_loc, C, dtype):
-    """back: [ep(dst), e_loc, C, d] results at their send slots → [T, d]."""
-    flat = jnp.concatenate(
-        [back.reshape(ep * e_loc * C, d),
-         jnp.zeros((1, d), back.dtype)], axis=0)
-    # global flat index of (expert_global, slot): expert-major like send.
-    gather_idx = jnp.where(
-        slot < C, top_i * C + slot, ep * e_loc * C)     # [T, k]
-    y = jnp.einsum("tkd,tk->td", flat[gather_idx],
-                   top_p.astype(back.dtype))
-    return y.astype(dtype)
+        sm = slot_map(top_i, mc.e_total, C)
+        top_p = top_p * sm.keep
+        send = dispatch_rows(x2d, sm)
+    return send.reshape(ep, mc.e_total // ep, C, d), top_p, sm
 
 
 def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
@@ -266,8 +242,7 @@ def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
             T = b * s
             x2d = x_loc.reshape(T, d)
             C = _pair_capacity(T, mc, ep, epc.capacity_factor)
-            send, top_p, top_i, slot = _dispatch_buffers(
-                x2d, router, mc, ep, C)
+            send, top_p, sm = _dispatch_buffers(x2d, router, mc, ep, C)
 
             if epc.mode == "baseline":
                 with jax.named_scope("moe/exchange"):
@@ -286,11 +261,12 @@ def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
                 back, rows = _hyperparallel_ring(
                     send, w_in, w_down, act, ep, epc)
 
-            y = _combine(back, top_p, top_i, slot, T, d, ep, e_loc, C,
-                         x_loc.dtype)
+            with jax.named_scope("moe/combine"):
+                # back: results at their send slots, expert-major like send.
+                y = combine_rows(back.reshape(mc.e_total, C, d), top_p, sm)
             counts = {k: v[None] for k, v in
-                      routing_counts(slot, C, rows).items()}
-            return y.reshape(b, s, d), counts
+                      routing_counts(sm.slot, C, rows).items()}
+            return y.astype(x_loc.dtype).reshape(b, s, d), counts
 
         y, counts = run(params["router"], params["w_in"],
                         params["w_down"], x)

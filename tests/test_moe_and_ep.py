@@ -2,6 +2,7 @@
 needs forced host devices, which must not leak into this process)."""
 
 import os
+import re
 import subprocess
 import sys
 
@@ -10,8 +11,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.models.moe import (MoEConfig, capacity, init_moe, make_dispatch,
-                              moe_dense_ref, moe_grouped, router_topk)
+from repro.models.moe import (MoEConfig, capacity, expert_ffn, init_moe,
+                              make_dispatch, moe_dense_ref, moe_grouped,
+                              router_topk, slot_map)
+from repro.parallel.ctx import moe_counts_scope
+from repro.parallel.ep import EPConfig, _pair_capacity, make_moe_ep
 
 KEY = jax.random.PRNGKey(0)
 MC = MoEConfig(n_experts=6, top_k=2, d_expert=16, capacity_factor=8.0,
@@ -48,13 +52,165 @@ def test_capacity_drop_consistency():
 def test_dispatch_slots_unique_per_expert():
     p = jnp.ones((16, 2)) / 2
     i = jnp.stack([jnp.arange(16) % 4, (jnp.arange(16) + 1) % 4], 1)
-    w, ii, slot = make_dispatch(p, i, 16, 4, 100)
+    w, ii, slot = make_dispatch(p, i, 4, 100)
     pairs = set()
     for t in range(16):
         for k in range(2):
             key = (int(ii[t, k]), int(slot[t, k]))
             assert key not in pairs, "slot collision"
             pairs.add(key)
+
+
+def _slot_loop(top_i: np.ndarray, C: int) -> np.ndarray:
+    """src [E, C] by a loop over make_dispatch's slots: the flat choice
+    t * k + j in each kept slot, T * k in empty ones."""
+    T, k = top_i.shape
+    E = int(top_i.max()) + 2
+    _, _, slot = make_dispatch(jnp.ones(top_i.shape), jnp.asarray(top_i),
+                               E, C)
+    slot = np.asarray(slot)
+    src = np.full((E, C), T * k)
+    for t in range(T):
+        for j in range(k):
+            if slot[t, j] < C:
+                src[top_i[t, j], slot[t, j]] = t * k + j
+    return src
+
+
+@pytest.mark.parametrize("C", [1, 3, 8])
+def test_slot_map_inverts_dispatch_slots(C):
+    """The sorted inverse map equals a loop over the cumsum slots, with
+    experts over capacity, an expert never chosen (the last) and drops;
+    dest points back at each kept choice."""
+    rng = np.random.default_rng(C)
+    top_i = rng.choice(6, size=(24, 3), p=[.4, .25, .15, .1, .06, .04])
+    top_i = np.stack([rng.permutation(6)[:3] if t % 5 == 0 else top_i[t]
+                      for t in range(24)])
+    E = int(top_i.max()) + 2
+    sm = slot_map(jnp.asarray(top_i), E, C)
+    want = _slot_loop(top_i, C)
+    np.testing.assert_array_equal(np.asarray(sm.src), want)
+    counts = np.bincount(top_i.reshape(-1), minlength=E)
+    assert counts[-1] == 0 and (counts > C).any()
+    keep = np.asarray(sm.keep).reshape(-1)
+    dest = np.asarray(sm.dest).reshape(-1)
+    np.testing.assert_array_equal(want.reshape(-1)[dest[keep]],
+                                  np.flatnonzero(keep))
+    assert (np.asarray(sm.slot).reshape(-1)[~keep] == C).all()
+
+
+MC_DROP = MoEConfig(n_experts=8, top_k=2, d_expert=16)
+
+
+def _scatter_reference(params, x, mc, C):
+    """Dispatch by a scatter-add into an [E, C + 1, d] buffer whose slot C
+    takes the dropped choices, and combine by gathering from it (the
+    gradient then scatter-adds both ways). Returns (y, counters)."""
+    B, S, d = x.shape
+    T, k, E = B * S, mc.top_k, mc.e_total
+    xt = x.reshape(T, d)
+    top_p, top_i = router_topk(params["router"], xt, mc)
+    flat_e = top_i.reshape(-1)
+    pos = jnp.cumsum(jax.nn.one_hot(flat_e, E, dtype=jnp.int32), 0) - 1
+    slot = jnp.take_along_axis(pos, flat_e[:, None], axis=1)[:, 0]
+    keep = slot < C
+    slot = jnp.where(keep, slot, C).reshape(T, k)
+    top_p = top_p * keep.reshape(T, k)
+    tok = jnp.broadcast_to(jnp.arange(T)[:, None], (T, k)).reshape(-1)
+    disp = jnp.zeros((E, C + 1, d), x.dtype).at[
+        flat_e, slot.reshape(-1)].add(xt[tok])[:, :C]
+    out = expert_ffn(params["w_in"], params["w_down"], disp)
+    out = jnp.concatenate([out, jnp.zeros_like(out[:, :1])], axis=1)
+    y = jnp.einsum("tkd,tk->td", out[top_i, slot], top_p.astype(x.dtype))
+    counts = {"moe_routed": T * k, "moe_dropped": jnp.sum(~keep),
+              "moe_slots": E * C}
+    return y.reshape(B, S, d), counts
+
+
+def _moe_path(path, mc, T, capacity_factor):
+    """(impl(params, x), C) for ``moe_grouped`` or an EP mode on a
+    one-device mesh, at the EP path's capacity for T tokens."""
+    C = _pair_capacity(T, mc, 1, capacity_factor)
+    if path == "grouped":
+        return (lambda p, x: moe_grouped(p, x, mc, cap=C)), C
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    ep_impl = make_moe_ep(mesh, EPConfig(path,
+                                         capacity_factor=capacity_factor))
+    return (lambda p, x: ep_impl(p, x, mc)), C
+
+
+MOE_PATHS = ["grouped", "baseline", "hyperparallel"]
+
+
+@pytest.mark.parametrize("path", MOE_PATHS)
+def test_gather_dispatch_matches_scatter_reference_with_drops(path):
+    """Capacity factor 0.5 drops choices; the gather-only dispatch and
+    combine give the scatter formulation's output bit for bit, its
+    gradients within f32 rounding, and the same counters."""
+    mc = MC_DROP
+    params = init_moe(KEY, 32, mc)
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 64, 32), jnp.float32)
+    impl, C = _moe_path(path, mc, x.shape[0] * x.shape[1], 0.5)
+
+    @jax.jit
+    def run(p, x):
+        with moe_counts_scope() as got:
+            y = impl(p, x)
+        (counts,) = got
+        return y, {k: jnp.sum(v) for k, v in counts.items()}
+
+    y, counts = run(params, x)
+    want_y, want_counts = jax.jit(
+        lambda p, x: _scatter_reference(p, x, mc, C))(params, x)
+    assert int(counts["moe_dropped"]) > 0
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(want_y))
+    for k in want_counts:
+        assert int(counts[k]) == int(want_counts[k]), k
+
+    def loss(f):
+        return lambda p, x: jnp.sum(jnp.sin(f(p, x)))
+    g = jax.jit(jax.grad(loss(impl), argnums=(0, 1)))(params, x)
+    want_g = jax.jit(jax.grad(loss(
+        lambda p, x: _scatter_reference(p, x, mc, C)[0]),
+        argnums=(0, 1)))(params, x)
+    for name in ("router", "w_in", "w_down"):
+        np.testing.assert_allclose(np.asarray(g[0][name]),
+                                   np.asarray(want_g[0][name]),
+                                   rtol=1e-5, atol=1e-5, err_msg=name)
+    np.testing.assert_allclose(np.asarray(g[1]), np.asarray(want_g[1]),
+                               rtol=1e-5, atol=1e-5, err_msg="x")
+
+
+def _under(op_name: str, scope: str) -> bool:
+    return re.search(r"(^|[/(])" + re.escape(scope) + r"($|[/)])",
+                     op_name) is not None
+
+
+@pytest.mark.parametrize("path", MOE_PATHS)
+def test_moe_gradient_has_no_row_scatter(path):
+    """Rows enter and leave the capacity buffer by gathers alone: the
+    compiled gradient has no scatter under ``moe/dispatch`` or
+    ``moe/combine`` (the router's top-k transpose may scatter)."""
+    mc = MC_DROP
+    params = init_moe(KEY, 32, mc, dtype=jnp.bfloat16)
+    x = jax.random.normal(KEY, (2, 64, 32), jnp.bfloat16)
+    impl, _ = _moe_path(path, mc, 128, 1.0)
+    grad = jax.jit(jax.grad(
+        lambda p, x: jnp.sum(impl(p, x).astype(jnp.float32) ** 2),
+        argnums=(0, 1)))
+    hlo = grad.lower(params, x).compile().as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+    for scope in ("moe/dispatch", "moe/combine"):
+        assert any(_under(n, scope) and "transpose(" in n
+                   for n in op_names), scope
+    scatters = [line for line in hlo.splitlines()
+                if re.search(r"= \S+ scatter\(", line)]
+    for line in scatters:
+        name = re.search(r'op_name="([^"]*)"', line)
+        assert name, line
+        assert not any(_under(name.group(1), s)
+                       for s in ("moe/dispatch", "moe/combine")), line
 
 
 def test_capacity_rounding():
