@@ -5,6 +5,8 @@ Everything that belongs to one cell, configuration, traffic mix or metric
 is a file of its own, found by the name ``BENCHMARK.json`` gives it:
 
 - ``configs/<config>.json``: the model and deployment as run;
+- ``arch/<model_type>.py``: how the configuration's architecture maps onto
+  the program, counts its FLOPs and names its layers (``arch``);
 - ``traffic/<traffic>.json``: parameters of the traffic generator;
 - ``workloads/<cell>.json``: the limits of the cell's comparison;
 - ``metrics/<metric>.py``: a ``read(run)`` that returns the metric's
@@ -17,6 +19,7 @@ import importlib.util
 import json
 import math
 import os
+import re
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -60,6 +63,7 @@ class Cell:
         self.per_layer = [m for m in bench["per_layer"]
                           if name in m.get("workloads", [name])]
         self.metrics_dir = os.path.join(here, "metrics")
+        self.arch = arch(self.config, root)
         if self.config["deployment"]["mesh"][0] \
                 * self.config["deployment"]["mesh"][1] != self.chips:
             raise SpecError(f"chipbench: {name} asks for {self.chips} chips "
@@ -78,6 +82,26 @@ def load_module(path: str, what: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+_ARCHS = {}
+
+
+def arch(cfg: dict, root: str = ROOT):
+    """The module of the configuration's architecture,
+    ``chipbench/arch/<model_type>.py``: ``SCOPES``, ``model_config``,
+    ``forward_flops_per_token`` and ``expert_ffn_least_s`` (the granite
+    module, ``arch/granitemoe.py``, says what each is). Loaded once a
+    process for each file; ``Cell.arch`` is the one its cell uses."""
+    name = str(cfg.get("model_type"))
+    if not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.\-]*", name):
+        raise SpecError(f"chipbench: configuration {cfg.get('name')!r} "
+                        f"states no model_type a module can be named by: "
+                        f"{name!r}")
+    path = os.path.join(root, "chipbench", "arch", name + ".py")
+    if path not in _ARCHS:
+        _ARCHS[path] = load_module(path, f"model_type {name!r}")
+    return _ARCHS[path]
 
 
 def reader(metrics_dir: str, name: str):

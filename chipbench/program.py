@@ -1,14 +1,14 @@
 """The system under test: the trainer's step, built as ``train.main`` does.
 
-Maps a configuration file onto the program's ``ModelConfig``, ``EPConfig``
-and ``OptConfig``, builds the sharded step with
+Maps a configuration file onto the program's ``ModelConfig`` (through the
+module of its ``model_type``, which the cell loaded), ``EPConfig`` and
+``OptConfig``, builds the sharded step with
 ``repro.launch.train.build_training`` and compiles it ahead of time for the
 cell's batch. Refuses a configuration the program cannot run as stated.
 """
 
 from __future__ import annotations
 
-import math
 import os
 import sys
 
@@ -22,44 +22,6 @@ def import_program():
         raise SystemExit(f"chipbench: no program under test at {src}")
     if src not in sys.path:
         sys.path.insert(0, src)
-
-
-def model_config(cfg: dict):
-    """The program's ModelConfig for a granite-style configuration file."""
-    import_program()
-    from repro.models.model import ModelConfig
-    from repro.models.moe import MoEConfig
-
-    d, H = cfg["hidden_size"], cfg["num_attention_heads"]
-    hd = cfg.get("head_dim") or d // H
-    # The program implements these at one value only.
-    fixed = {"embedding_multiplier": 1.0, "residual_multiplier": 1.0,
-             "logits_scaling": 1.0, "attention_multiplier": hd ** -0.5,
-             "rms_norm_eps": 1e-6, "hidden_act": "silu",
-             "attention_bias": False}
-    for key, want in fixed.items():
-        got = cfg[key]
-        same = (got == want if isinstance(want, (str, bool))
-                else math.isclose(got, want, rel_tol=1e-9))
-        if not same:
-            raise SystemExit(f"chipbench: the program runs {key}={want!r} "
-                             f"only; the configuration states {got!r}")
-    prog = cfg["program"]
-    dep = cfg["deployment"]
-    return ModelConfig(
-        name=cfg["name"], family="moe",
-        n_layers=cfg["num_hidden_layers"], d_model=d, n_heads=H,
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=hd,
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        act="swiglu", norm="rmsnorm", rope_theta=float(cfg["rope_theta"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        moe=MoEConfig(n_experts=cfg["num_local_experts"],
-                      top_k=cfg["num_experts_per_tok"],
-                      d_expert=cfg["intermediate_size"],
-                      n_padding_experts=dep["padding_experts"]),
-        vocab_pad=prog["vocab_pad"], dtype=cfg["dtype"],
-        remat=prog["remat"], remat_policy=prog["remat_policy"],
-        attn_block=prog["attn_block"])
 
 
 def opt_config(cfg: dict):
@@ -77,7 +39,7 @@ def opt_config(cfg: dict):
 class Program:
     """The compiled train step of one cell and what it runs on."""
 
-    def __init__(self, cfg: dict, batch: int, seq: int, devices):
+    def __init__(self, cfg: dict, arch, batch: int, seq: int, devices):
         import_program()
         import jax
 
@@ -90,7 +52,7 @@ class Program:
         self.mesh = jax.make_mesh(
             (data, model), ("data", "model"), devices=devices[:data * model],
             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-        self.cfg = model_config(cfg)
+        self.cfg = arch.model_config(cfg)
         if self.cfg.moe.e_total % model:
             raise SystemExit(f"chipbench: {self.cfg.moe.e_total} experts do "
                              f"not divide over {model} chips")

@@ -2,9 +2,12 @@
 summed per scope over the traced steps, and the device's idle time by the
 phase the trainer's loop was in.
 
-The program names its layers with ``jax.named_scope`` (``SCOPES``); JAX
-writes the scope path into each HLO instruction's ``op_name`` metadata,
-through ``jvp``, ``transpose`` and remat alike (``transpose(jvp(attention))``,
+The program names its layers with ``jax.named_scope``; the module of the
+configuration's architecture lists those names (``SCOPES`` of
+``chipbench/arch/<model_type>.py``), and every function here that needs
+them takes them as ``scopes``. JAX writes the scope path into each HLO
+instruction's ``op_name`` metadata, through ``jvp``, ``transpose`` and
+remat alike (``transpose(jvp(attention))``,
 ``.../checkpoint/rematted_computation/moe/dispatch/...``). A v5e trace's
 XLA Ops events carry the instruction's name (``%fusion.686 = bf16[...]``)
 and no scope, so ``op_scopes`` reads the map from instruction name to scope
@@ -18,28 +21,19 @@ known scope counts as ``other``.
 
 The trainer's loop (``repro.ft.runner.train_loop``) opens a ``train_step``
 span per step (a ``StepTraceAnnotation`` whose ``step_num`` is kept) and
-``train_loop/*`` spans for its phases; ``program_spans`` reads them from the
-same ``.xplane.pb`` as ``tracereduce.load`` reads the device ops, into the
-trace's ``program`` key.
+``train_loop/*`` spans for its phases; ``tracereduce.load`` reads them into
+the trace's ``program`` key.
 """
 
 from __future__ import annotations
 
 import collections
-import glob
-import os
 import re
 
 from chipbench import tracereduce
 
-SCOPES = ("embed", "attention", "moe/router", "moe/dispatch",
-          "moe/exchange", "moe/expert_ffn", "moe/combine", "unembed_ce",
-          "optimizer")
 OTHER = "other"
-STEP_SPAN = "train_step"
-PHASE_PREFIX = "train_loop/"
 
-_PARTS = [tuple(s.split("/")) for s in SCOPES]
 _SEP = re.compile(r"[/()]")
 _HEADER = re.compile(r"(?:ENTRY\s+)?%([^\s(]+) \(.*\{\s*$")
 _INSTR = re.compile(r"\s*(ROOT\s+)?%([^\s=]+) = ")
@@ -47,13 +41,13 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _REF = re.compile(r"%([\w.\-]+)")
 
 
-def scope_of(op_name: str) -> str:
-    """The innermost known scope on an ``op_name`` path, or ``other``.
+def scope_of(op_name: str, scopes) -> str:
+    """The innermost of ``scopes`` on an ``op_name`` path, or ``other``.
     Transformations wrap scopes in parentheses (``transpose(jvp(embed))``),
     so the path is split on ``/``, ``(`` and ``)``."""
     segs = [s for s in _SEP.split(op_name) if s]
     for end in range(len(segs), 0, -1):
-        for parts in _PARTS:
+        for parts in (tuple(s.split("/")) for s in scopes):
             if tuple(segs[end - len(parts):end]) == parts:
                 return "/".join(parts)
     return OTHER
@@ -65,7 +59,7 @@ def instruction(event_name: str) -> str:
     return event_name.partition(" = ")[0].strip().lstrip("%")
 
 
-def _parse(hlo_text: str):
+def _parse(hlo_text: str, scopes):
     """Per instruction: its ``op_name`` scope (None without one), the
     computations it calls, its operands; per computation: its root and its
     instructions."""
@@ -83,7 +77,7 @@ def _parse(hlo_text: str):
             continue
         name = m.group(2)
         n = _OP_NAME.search(line)
-        own[name] = scope_of(n.group(1)) if n is not None else None
+        own[name] = scope_of(n.group(1), scopes) if n is not None else None
         members[comp].append(name)
         if m.group(1):
             roots[comp] = name
@@ -95,10 +89,10 @@ def _parse(hlo_text: str):
     return own, calls, operands, roots, members
 
 
-def op_scopes(hlo_text: str) -> dict:
+def op_scopes(hlo_text: str, scopes) -> dict:
     """``{instruction name: scope}`` for every instruction of an HLO
-    module's text."""
-    own, calls, operands, roots, members = _parse(hlo_text)
+    module's text, each one of ``scopes`` or ``other``."""
+    own, calls, operands, roots, members = _parse(hlo_text, scopes)
     memo: dict = {}
 
     def votes(comp, seen):
@@ -131,33 +125,12 @@ def op_scopes(hlo_text: str) -> dict:
     return {name: resolve(name) for name in own}
 
 
-def program_spans(trace_dir: str) -> list:
-    """The loop's spans in a trace directory, ``(name, start_ns, end_ns,
-    step_num or None)`` in start order; empty where the program opens
-    none."""
-    import jax
-
-    path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                            recursive=True))[-1]
-    out = []
-    for plane in jax.profiler.ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            out.extend((e.name, float(e.start_ns), float(e.end_ns),
-                        dict(e.stats).get("step_num")
-                        if e.name == STEP_SPAN else None)
-                       for e in line.events if e.name == STEP_SPAN
-                       or e.name.startswith(PHASE_PREFIX))
-    return sorted(out, key=lambda e: e[1])
-
-
 def steps(trace: dict) -> list:
     """``(step, start, end)`` of each ``train_step`` span that lies inside
     the traced window; empty where the program opens none."""
     lo, hi = tracereduce.window(trace)
     return [(st, s, e) for n, s, e, st in trace.get("program", [])
-            if n == STEP_SPAN and s >= lo and e <= hi]
+            if n == tracereduce.STEP_SPAN and s >= lo and e <= hi]
 
 
 def idle_by_phase(trace: dict):
@@ -169,7 +142,8 @@ def idle_by_phase(trace: dict):
     spans = steps(trace)
     if not spans or not trace["devices"]:
         return None
-    phases = [p for p in trace["program"] if p[0].startswith(PHASE_PREFIX)]
+    phases = [p for p in trace["program"]
+              if p[0].startswith(tracereduce.PHASE_PREFIX)]
     worst = None
     for ev in trace["devices"].values():
         by = dict.fromkeys([n for n, *_ in phases] + [OTHER], 0.0)
@@ -187,16 +161,16 @@ def idle_by_phase(trace: dict):
     return {n: v / 1e6 / len(spans) for n, v in worst.items()}
 
 
-def layer_ms(trace: dict, op_scope: dict):
+def layer_ms(trace: dict, op_scope: dict, scopes):
     """Device ms per ``train_step`` span of the traced window, mean over
-    devices: self time under each scope of ``SCOPES``, ``other``, and
+    devices: self time under each of ``scopes``, ``other``, and
     ``busy_total``, the union of the ops' intervals. None without step
     spans, or where no op maps to a known scope."""
     spans = steps(trace)
     if (not spans or not trace["devices"]
             or not any(v != OTHER for v in op_scope.values())):
         return None
-    out = dict.fromkeys(SCOPES + (OTHER,), 0.0)
+    out = dict.fromkeys(tuple(scopes) + (OTHER,), 0.0)
     busy = 0.0
     for ev in trace["devices"].values():
         for _, s, e in spans:

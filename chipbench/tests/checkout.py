@@ -44,7 +44,7 @@ def make(dst: str, chips: int = 1, src: bool = True) -> str:
                 os.path.join(here, "workloads", CELL + ".json"))
     with open(os.path.join(here, "peaks.json")) as f:
         p = json.load(f)
-    p["devices"]["cpu"] = {"bf16_flops_per_s": 1e12}
+    p["devices"]["cpu"] = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
     _dump(p, here, "peaks.json")
     b = _bench(dst)
     b["configs"].append({"name": "tiny", "source": "test",
@@ -84,6 +84,33 @@ mod = runpy.run_path(os.path.join(root, "chipbench", "run.py"),
                      run_name="chipbench_test")
 sys.exit(mod["main"](sys.argv[3:], require=lambda n: jax.devices()[:n]))
 '''
+
+
+# On the CPU the XLA ops run on the host's XLA threads, not on a device's
+# ops line, so a trace holds no device ops. Planted before a traced run, this
+# reads each XLA thread's op events (named by their HLO instruction) as one
+# device's ops line, so that the layer times come through on the CPU.
+CPU_OPS = r"""
+import glob, re
+import chipbench.tracereduce as _T
+_load = _T.load
+def _cpu_load(trace_dir):
+    out = _load(trace_dir)
+    path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    lines = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            lines += [sorted(((e.name, float(e.start_ns), float(e.end_ns))
+                              for e in line.events
+                              if re.fullmatch(r"[\w\-]+\.\d+|wrapped_\w+",
+                                              e.name)), key=lambda e: e[1])
+                      for line in plane.lines
+                      if line.name.startswith("tf_XLA")]
+    out["devices"] = dict(enumerate(ev for ev in lines if ev))
+    return out
+_T.load = _cpu_load
+"""
 
 
 def run(root: str, args: list, plant: str = "", devices: int = 1,

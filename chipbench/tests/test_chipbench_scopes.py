@@ -1,20 +1,21 @@
 """The reading of the program's measurement points on the CPU: op names to
 named scopes, device time by scope and idle time by loop phase over the
 ``train_step`` spans, the per-step readings ``chipbench/layers.py`` makes
-of them, and its runs on the program and on a program that has no spans
-and no counters."""
+of them, and traced runs of the benchmark on the program and on a program
+that has no spans and no counters."""
 
 from __future__ import annotations
 
 import gzip
 import json
 import os
-import subprocess
-import sys
+import types
 
 import pytest
 
 from chipbench import harness, layers, scopes
+from chipbench.arch import granitemoe
+from chipbench.arch.granitemoe import SCOPES
 from chipbench.tests import checkout
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -41,7 +42,7 @@ READINGS = ("moe_dispatch_ms", "moe_expert_ffn_ms", "moe_combine_ms",
 ], ids=["fwd", "transposed", "not_embed", "remat", "nested_call",
         "nested_scope", "innermost", "unscoped", "partial_name", "empty"])
 def test_scope_of_by_hand(op_name, scope):
-    assert scopes.scope_of(op_name) == scope
+    assert scopes.scope_of(op_name, SCOPES) == scope
 
 
 def test_op_scopes_from_hlo_text():
@@ -83,7 +84,7 @@ def test_op_scopes_from_hlo_text():
         '  ROOT %while.9 = (s32[]) while(%constant.1), condition=%c, '
         'body=%b, metadata={op_name="jit(f)/jvp()/while"}',
         "}"])
-    got = scopes.op_scopes(text)
+    got = scopes.op_scopes(text, SCOPES)
     # named: its own op_name; a fusion with none: its fused root's, else
     # what most fused instructions carry; a copy: its operand's.
     assert got["fusion.686"] == "moe/dispatch"
@@ -137,7 +138,7 @@ OP_SCOPE = {"while.9": "other", "fusion.1": "moe/dispatch",
 def test_layer_ms_by_hand():
     t = _trace()
     assert [s[0] for s in scopes.steps(t)] == [7, 8]
-    ms = scopes.layer_ms(t, OP_SCOPE)
+    ms = scopes.layer_ms(t, OP_SCOPE, SCOPES)
     # ns summed over two steps, per step, in ms.
     assert ms["moe/dispatch"] == pytest.approx(50 / 2e6)
     assert ms["moe/expert_ffn"] == pytest.approx(120 / 2e6)
@@ -147,9 +148,9 @@ def test_layer_ms_by_hand():
     # the loop op's own 30 ns, and the copy no scope names
     assert ms["other"] == pytest.approx((30 + 20) / 2e6)
     assert ms["busy_total"] == pytest.approx((200 + 80 + 160) / 2e6)
-    assert sum(ms[k] for k in scopes.SCOPES + ("other",)) == \
+    assert sum(ms[k] for k in SCOPES + ("other",)) == \
         pytest.approx(ms["busy_total"])
-    assert set(ms) == set(scopes.SCOPES) | {"other", "busy_total"}
+    assert set(ms) == set(SCOPES) | {"other", "busy_total"}
 
 
 def test_idle_by_phase_by_hand():
@@ -170,12 +171,12 @@ def test_idle_by_phase_by_hand():
 def test_no_spans_or_scopes_read_nothing():
     t = _trace()
     no_spans = dict(t, program=[])
-    assert scopes.layer_ms(no_spans, OP_SCOPE) is None
+    assert scopes.layer_ms(no_spans, OP_SCOPE, SCOPES) is None
     assert scopes.idle_by_phase(no_spans) is None
     no_program = {k: v for k, v in t.items() if k != "program"}
     assert scopes.steps(no_program) == []
-    assert scopes.layer_ms(t, {"while.9": "other"}) is None
-    assert scopes.layer_ms(t, {}) is None
+    assert scopes.layer_ms(t, {"while.9": "other"}, SCOPES) is None
+    assert scopes.layer_ms(t, {}, SCOPES) is None
 
 
 def _granite():
@@ -184,7 +185,7 @@ def _granite():
         return json.load(f)
 
 
-LAYER_MS = dict(dict.fromkeys(scopes.SCOPES, 1.0), **{"moe/expert_ffn": 40.0})
+LAYER_MS = dict(dict.fromkeys(SCOPES, 1.0), **{"moe/expert_ffn": 40.0})
 IDLE_MS = {"train_loop/batch": 1.5, "train_loop/wait": 2.0,
            "train_loop/log": 0.25, "train_loop/hook": 0.25}
 COUNTS = [{"moe_routed": 100, "moe_dropped": 10, "moe_slots": 400}] * 2
@@ -202,14 +203,16 @@ COUNTS = [{"moe_routed": 100, "moe_dropped": 10, "moe_slots": 400}] * 2
         "counters_partial"])
 def test_readings_leave_out_what_is_missing(layer_ms, idle_ms, counts,
                                             want):
-    got = layers.readings(_granite(), harness.peaks("TPU v5 lite"), 1,
-                          layer_ms, idle_ms, counts)
+    got = layers.readings(granitemoe, _granite(),
+                          harness.peaks("TPU v5 lite"), 1, layer_ms,
+                          idle_ms, counts)
     assert set(got) == want
 
 
 def test_readings_by_hand():
     cfg, peaks = _granite(), harness.peaks("TPU v5 lite")
-    got = layers.readings(cfg, peaks, 1, LAYER_MS, IDLE_MS, COUNTS)
+    got = layers.readings(granitemoe, cfg, peaks, 1, LAYER_MS, IDLE_MS,
+                          COUNTS)
     assert set(got) == set(READINGS) | {"expert_ffn_bound"}
     assert got["moe_expert_ffn_ms"] == 40.0
     assert got["expert_slot_fill"] == pytest.approx(22.5)
@@ -225,21 +228,6 @@ def test_readings_by_hand():
         100 * max(flops / peaks["bf16_flops_per_s"],
                   nbytes / peaks["hbm_bytes_per_s"]) / 0.040)
 
-
-# Runs chipbench/layers.py with the look for a chip replaced by the CPU's
-# devices, and, where given, a plant in the program first.
-LAYERS_MAIN = r"""
-import os, runpy, sys
-root, plant = sys.argv[1], sys.argv[2]
-sys.path.insert(0, root)
-sys.path.insert(0, os.path.join(root, "src"))
-import jax
-jax.config.update("jax_cpu_enable_async_dispatch", False)
-exec(plant)
-mod = runpy.run_path(os.path.join(root, "chipbench", "layers.py"),
-                     run_name="chipbench_test")
-sys.exit(mod["main"](sys.argv[3:], require=lambda n: jax.devices()[:n]))
-"""
 
 # A program whose loop opens no spans and whose step returns no counters,
 # as before they were added.
@@ -259,43 +247,55 @@ St.make_steps = make_steps
 """
 
 
-@pytest.mark.parametrize("plant", ["", NO_SPANS_OR_COUNTERS],
-                         ids=["program", "program_without_spans_or_counters"])
-def test_layers_tool_on_cpu(tmp_path, plant):
-    """On the CPU the trace holds no TPU ops, so no layer or idle times;
-    the program's step spans and counters still come through, and a
-    program without them gives an empty reading, not an error."""
-    root = checkout.make(str(tmp_path))
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               JAX_COMPILATION_CACHE_DIR=os.path.join(root, ".jax_cache"))
-    p = subprocess.run(
-        [sys.executable, "-c", LAYERS_MAIN, root, plant, "--workload",
-         checkout.CELL, "--seed", str(2 ** 41 + 3)],
-        cwd=root, env=env, text=True, capture_output=True, timeout=300)
+def _traced_line(root, seconds, plant=""):
+    p = checkout.run(root, ["--workload", checkout.CELL, "--seed",
+                            str(2 ** 41 + 3), "--seconds", str(seconds),
+                            "--trace", "1"], plant=checkout.CPU_OPS + plant)
     assert p.returncode == 0, p.stderr[-3000:]
     line = checkout.last_line(p.stdout)
-    assert line["layer_ms"] is None and line["idle_ms"] is None
+    assert line["correct"] is True, line["checks"]
+    return line
+
+
+@pytest.mark.parametrize("plant", ["", NO_SPANS_OR_COUNTERS],
+                         ids=["program", "program_without_spans_or_counters"])
+def test_traced_line_reads_the_checked_steps_on_cpu(tmp_path, plant):
+    """A traced run carries the layer readings, with the counters of the
+    checked steps (0-2): two runs of one seed whose windows hold different
+    numbers of steps read the same counters, slot fill and dropped
+    choices. A program without spans or counters leaves out what they
+    give, and is not an error."""
+    root = checkout.make(str(tmp_path))
+    line = _traced_line(root, 1, plant)
+    got = {k: v["value"] for k, v in line["metrics"].items()}
     if plant:
-        assert line["steps"] == [] and line["readings"] == {}
+        assert line["counters"] == [{"step": st} for st in range(3)]
+        assert line["idle_ms"] is None and line["layer_ms"] is None
+        assert "dropped_choices" not in line["routing"]
+        assert not set(READINGS) & set(got)
         return
-    assert line["steps"] == list(range(layers.WARM_STEPS + 1,
-                                       layers.WARM_STEPS + layers.TRACE_STEPS))
-    assert line["step_ms"] > 0
+    assert [c["step"] for c in line["counters"]] == [0, 1, 2]
     # tiny: 2 layers, top-2 of 8 experts over 2 x 64 tokens a step, 128
     # rows an expert (4 x the mean of 32).
     for c in line["counters"]:
         assert c["moe_routed"] == 2 * 128 * 2
         assert c["moe_slots"] == 2 * 8 * 128
         assert 0 <= c["moe_dropped"] <= c["moe_routed"]
-    assert set(line["readings"]) == {"expert_slot_fill"}
+    assert set(READINGS) <= set(got)
+    kept = sum(c["moe_routed"] - c["moe_dropped"] for c in line["counters"])
+    assert got["expert_slot_fill"] == pytest.approx(
+        100 * kept / (3 * 2 * 8 * 128), rel=1e-12)
+    assert line["routing"]["dropped_choices"] == sum(
+        c["moe_dropped"] for c in line["counters"])
+    longer = _traced_line(root, 3)
+    assert longer["attempted"] > line["attempted"]
+    assert longer["counters"] == line["counters"]
+    assert longer["routing"] == line["routing"]
+    assert longer["metrics"]["expert_slot_fill"] == \
+        line["metrics"]["expert_slot_fill"]
 
 
-def test_scoped_steps_recorded_on_chip():
-    """Two steps of a `--trace 1` run of `granite-4l.s2048` on a v5e chip
-    (op names shortened), with the trainer loop's spans and the op→scope
-    map of the compiled step: the reduction gives what it gave when the
-    fixture was made, the scopes and ``other`` add up to the busy time,
-    and the named scopes hold at least 90% of it."""
+def _recorded():
     with gzip.open(os.path.join(HERE, "fixtures",
                                 "scoped_steps_1chip.json.gz"), "rt") as f:
         rec = json.load(f)
@@ -304,11 +304,53 @@ def test_scoped_steps_recorded_on_chip():
                      for k, v in ev["devices"].items()},
          "host": [tuple(e) for e in ev["host"]],
          "program": [tuple(e) for e in ev["program"]]}
+    return rec, t
+
+
+# Counters of one granite-4l.s2048 step on one chip: 4 layers x 4096
+# tokens x top-8 choices, 40 x 3280 slots a layer, about 11600 dropped.
+CHIP_COUNTS = [{"moe_routed": 131072, "moe_dropped": 11590,
+                "moe_slots": 524800}] * 2
+
+
+def test_readers_equal_layers_readings_on_recorded_steps():
+    """The benchmark's readers of the nine layer readings, on the recorded
+    chip steps, read what ``layers.readings`` reads from the same input,
+    and the roofline share stays under 100%."""
+    rec, t = _recorded()
+    cfg, peaks = _granite(), harness.peaks("TPU v5 lite")
+    run = types.SimpleNamespace(
+        cell=types.SimpleNamespace(arch=granitemoe), config=cfg,
+        peaks=peaks, chips=1,
+        layer_ms=scopes.layer_ms(t, rec["scopes"], SCOPES),
+        idle_ms=scopes.idle_by_phase(t), counts=CHIP_COUNTS)
+    want = layers.readings(granitemoe, cfg, peaks, 1, run.layer_ms,
+                           run.idle_ms, CHIP_COUNTS)
+    metrics = os.path.join(checkout.ROOT, "chipbench", "metrics")
+    got = {name: harness.reader(metrics, name)(run) for name in READINGS}
+    assert got == {k: want[k] for k in READINGS}
+    assert 0 < got["expert_ffn_roofline"] <= 100
+    assert got["moe_dispatch_ms"] == rec["expect"]["layer_ms"][
+        "moe/dispatch"]
+    bench = harness.benchmark()
+    per_layer = {m["name"]: m for m in bench["per_layer"]}
+    for name in READINGS:
+        assert set(per_layer[name]["workloads"]) == {
+            w["name"] for w in bench["workloads"]}
+
+
+def test_scoped_steps_recorded_on_chip():
+    """Two steps of a `--trace 1` run of `granite-4l.s2048` on a v5e chip
+    (op names shortened), with the trainer loop's spans and the op→scope
+    map of the compiled step: the reduction gives what it gave when the
+    fixture was made, the scopes and ``other`` add up to the busy time,
+    and the named scopes hold at least 90% of it."""
+    rec, t = _recorded()
     want = rec["expect"]
     assert [s[0] for s in scopes.steps(t)] == want["steps"]
-    ms = scopes.layer_ms(t, rec["scopes"])
+    ms = scopes.layer_ms(t, rec["scopes"], SCOPES)
     assert ms == pytest.approx(want["layer_ms"], rel=1e-9)
-    named = sum(ms[k] for k in scopes.SCOPES)
+    named = sum(ms[k] for k in SCOPES)
     assert named + ms["other"] == pytest.approx(ms["busy_total"], rel=0.01)
     assert named >= 0.9 * ms["busy_total"]
     assert scopes.idle_by_phase(t) == pytest.approx(want["idle_ms"],

@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import glob
 import gzip
+import hashlib
 import json
 import os
 import shutil
@@ -41,8 +42,8 @@ def test_model_flops_by_hand():
 
 def _trace():
     # Two chips. Chip 0 runs a loop whose body computes twice, then
-    # computes and idles. Host spans: the traced window, two step
-    # dispatches, a wait.
+    # computes and idles. Host spans: the traced window; the loop's: two
+    # step dispatches, a wait.
     return {
         "devices": {
             0: [("%while.9 = (bf16[8]) while(...)", 100, 500),
@@ -51,9 +52,11 @@ def _trace():
                 ("%fusion.5", 700, 900)],
             1: [("%fusion.1 = bf16[8]{0} fusion(...)", 100, 600)],
         },
-        "host": [("traced_window", 0, 1000), ("step_dispatch", 50, 90),
-                 ("step_dispatch", 600, 620),
-                 ("loop_wait_and_log", 500, 700)],
+        "host": [("traced_window", 0, 1000)],
+        "program": [("train_step", 40, 1000, 3),
+                    ("train_loop/dispatch", 50, 90, None),
+                    ("train_loop/dispatch", 600, 620, None),
+                    ("train_loop/wait", 500, 700, None)],
     }
 
 
@@ -73,8 +76,9 @@ def test_trace_reducer_by_hand():
     # chip 1 idles most: [0, 100] under a dispatch, [600, 1000] partly
     # under the wait.
     gaps = dict(b["idle_gaps"])
-    assert gaps["step_dispatch"] == pytest.approx(100 / 1e9)
-    assert gaps["loop_wait_and_log"] == pytest.approx(400 / 1e9)
+    assert gaps["train_loop/dispatch"] == pytest.approx(100 / 1e9)
+    assert gaps["train_loop/wait"] == pytest.approx(400 / 1e9)
+    assert "train_step" not in gaps
 
 
 @pytest.mark.parametrize("path", sorted(glob.glob(os.path.join(
@@ -110,6 +114,121 @@ def test_missing_names_are_refused(tmp_path):
         harness.peaks("TPU v99")
     with pytest.raises(SystemExit, match="has no file"):
         harness.reader(os.path.join(root, "chipbench", "metrics"), "nope")
+    # A configuration whose model_type has no module is refused as its
+    # cell loads, naming the file it looked for.
+    path = os.path.join(root, "chipbench", "configs", "tiny.json")
+    with open(path) as f:
+        c = json.load(f)
+    for model_type, want in (("nope", "arch/nope.py"),
+                             ("../configs/tiny", "no model_type")):
+        checkout._dump(dict(c, model_type=model_type), path)
+        with pytest.raises(harness.SpecError, match=want):
+            harness.Cell(checkout.CELL, root)
+
+
+def test_granite_model_config_is_pinned():
+    """The granite module maps the granite file onto the program's
+    ModelConfig field for field as the benchmark has run it."""
+    from chipbench import program
+    from chipbench.arch import granitemoe
+
+    program.import_program()
+    from repro.models.model import ModelConfig
+    from repro.models.moe import MoEConfig
+
+    assert granitemoe.model_config(_config()) == ModelConfig(
+        name="granite-moe-3b-a800m-4l", family="moe", n_layers=4,
+        d_model=1536, n_heads=24, n_kv_heads=8, head_dim=64, d_ff=512,
+        vocab=49155, act="swiglu", norm="rmsnorm", rope_theta=10000.0,
+        tie_embeddings=True,
+        moe=MoEConfig(n_experts=40, top_k=8, d_expert=512,
+                      n_padding_experts=0),
+        vocab_pad=256, dtype="bfloat16", remat=True, remat_policy="full",
+        attn_block=1024)
+
+
+def _hashes(root):
+    out = {}
+    for d, _, files in os.walk(root):
+        for name in files:
+            path = os.path.join(d, name)
+            if "__pycache__" in path or ".jax_cache" in path \
+                    or ".chipbench" in path:
+                continue
+            with open(path, "rb") as f:
+                out[os.path.relpath(path, root)] = hashlib.sha256(
+                    f.read()).hexdigest()
+    return out
+
+
+# A second architecture, as a new module: granite's mapping onto the
+# program, with the whole MoE layer read as one scope.
+TWIN_ARCH = """
+import os
+from chipbench import harness
+_g = harness.load_module(
+    os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                 "granitemoe.py"), "granitemoe")
+SCOPES = ("attention", "moe")
+model_config = _g.model_config
+forward_flops_per_token = _g.forward_flops_per_token
+expert_ffn_least_s = _g.expert_ffn_least_s
+"""
+
+MOE_LAYER_MS = """
+def read(run):
+    if run.layer_ms is None:
+        return None
+    return run.layer_ms.get("moe")
+"""
+
+
+def test_new_architecture_is_found_by_name(tmp_path):
+    """A model_type module, its configuration, a reference copy, a cell and
+    a reader of one of the module's scopes, added as new files and entries
+    in BENCHMARK.json, run with no other file changed, and the traced line
+    carries the reader's metric."""
+    root = checkout.make(str(tmp_path))
+    here = os.path.join(root, "chipbench")
+    before = _hashes(root)
+    with open(os.path.join(here, "configs", "tiny.json")) as f:
+        c = json.load(f)
+    c.update(name="tiny-twin", model_type="twinmoe", reference="twin_ref")
+    checkout._dump(c, here, "configs", "tiny-twin.json")
+    with open(os.path.join(here, "arch", "twinmoe.py"), "w") as f:
+        f.write(TWIN_ARCH)
+    shutil.copy(os.path.join(here, "reference", "granite_moe.py"),
+                os.path.join(here, "reference", "twin_ref.py"))
+    shutil.copy(os.path.join(here, "workloads", checkout.CELL + ".json"),
+                os.path.join(here, "workloads", "tiny-twin.s64.json"))
+    with open(os.path.join(here, "metrics", "moe_layer_ms.py"), "w") as f:
+        f.write(MOE_LAYER_MS)
+    b = checkout._bench(root)
+    b["configs"].append({"name": "tiny-twin", "source": "test",
+                         "file": "chipbench/configs/tiny-twin.json",
+                         "reduced": [], "why": "test"})
+    b["workloads"].append({"name": "tiny-twin.s64", "config": "tiny-twin",
+                           "traffic": "s64", "chips": 1, "why": "test"})
+    b["per_layer"].append({"name": "moe_layer_ms", "unit": "ms",
+                           "better": "lower", "source": "device_trace",
+                           "layer": "test", "moves": "train_tokens_per_s",
+                           "workloads": ["tiny-twin.s64"]})
+    checkout._dump(b, root, "BENCHMARK.json")
+    p = checkout.run(root, ["--workload", "tiny-twin.s64", "--seed",
+                            str(2 ** 40 + 9), "--seconds", "1", "--trace",
+                            "1"], plant=checkout.CPU_OPS)
+    assert p.returncode == 0, p.stderr[-3000:]
+    line = checkout.last_line(p.stdout)
+    assert line["correct"] is True, line["checks"]
+    assert set(line["layer_ms"]) == {"attention", "moe", "other",
+                                     "busy_total"}
+    assert line["metrics"]["moe_layer_ms"]["value"] == \
+        line["layer_ms"]["moe"] > 0
+    assert {"mfu", "compiles_in_window"} <= set(line["metrics"])
+    after = _hashes(root)
+    # BENCHMARK.json gains the new entries; no other file changed.
+    changed = {k for k in before if before[k] != after.get(k)}
+    assert changed == {"BENCHMARK.json"}
 
 
 def test_refuses_without_tpu(tmp_path):

@@ -4,9 +4,10 @@ gap.
 
 ``load`` reads the ``.xplane.pb`` that ``jax.profiler`` writes into plain
 events: per device, the XLA operations as ``(name, start_ns, end_ns)``; for
-the host, the benchmark's own spans (``jax.profiler.TraceAnnotation``). The
-rest works on those events only, so a small recorded trace kept as a
-fixture checks it.
+the host, the benchmark's ``traced_window`` span, and the trainer loop's
+own spans (``train_step`` with its ``step_num``, and the ``train_loop/*``
+phases). The rest works on those events only, so a small recorded trace
+kept as a fixture checks it.
 """
 
 from __future__ import annotations
@@ -18,14 +19,18 @@ DEVICE_PREFIX = "/device:TPU:"
 # Operations as the core runs them; a loop or conditional op spans the ops
 # of its body, which the same line holds nested inside it.
 OPS_LINE = "XLA Ops"
-# Spans the benchmark opens around its own hooks in the trainer's loop.
-HOST_SPANS = ("batch_placement", "step_dispatch", "loop_wait_and_log",
-              "traced_window")
+# The span the benchmark opens around the traced steps.
+WINDOW_SPAN = "traced_window"
+# The trainer loop's spans: one per step, and one per phase of a step.
+STEP_SPAN = "train_step"
+PHASE_PREFIX = "train_loop/"
 
 
 def load(trace_dir: str) -> dict:
-    """``{"devices": {id: [(name, start, end), ...]}, "host": [...]}``,
-    times in ns on the trace's clock."""
+    """``{"devices": {id: [(name, start, end), ...]}, "host": [...],
+    "program": [(name, start, end, step_num or None), ...]}``, times in ns
+    on the trace's clock; ``program`` is empty where the loop opens no
+    spans."""
     import jax
 
     paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
@@ -33,7 +38,7 @@ def load(trace_dir: str) -> dict:
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
     data = jax.profiler.ProfileData.from_file(paths[-1])
-    devices, host = {}, []
+    devices, host, program = {}, [], []
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PREFIX):
             dev = int(plane.name[len(DEVICE_PREFIX):])
@@ -44,9 +49,17 @@ def load(trace_dir: str) -> dict:
                          for e in line.events), key=lambda e: e[1])
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                host.extend((e.name, float(e.start_ns), float(e.end_ns))
-                            for e in line.events if e.name in HOST_SPANS)
-    return {"devices": devices, "host": sorted(host, key=lambda e: e[1])}
+                for e in line.events:
+                    span = (e.name, float(e.start_ns), float(e.end_ns))
+                    if e.name == WINDOW_SPAN:
+                        host.append(span)
+                    elif e.name == STEP_SPAN:
+                        program.append(span + (dict(e.stats).get(
+                            "step_num"),))
+                    elif e.name.startswith(PHASE_PREFIX):
+                        program.append(span + (None,))
+    return {"devices": devices, "host": sorted(host, key=lambda e: e[1]),
+            "program": sorted(program, key=lambda e: e[1])}
 
 
 def short_name(name: str) -> str:
@@ -100,7 +113,7 @@ def structure(trace_dir: str, top: int = 25) -> dict:
 
 def window(trace: dict) -> tuple[float, float]:
     """The traced window: the benchmark's ``traced_window`` span."""
-    spans = [e for e in trace["host"] if e[0] == "traced_window"]
+    spans = [e for e in trace["host"] if e[0] == WINDOW_SPAN]
     if not spans:
         raise ValueError("trace has no traced_window span")
     return spans[0][1], spans[0][2]
@@ -147,10 +160,11 @@ def idle_share(trace: dict) -> dict:
 
 
 def host_label(trace: dict, s: float, e: float) -> str:
-    """The benchmark span that overlaps an interval most, or ``other``."""
+    """The trainer loop's phase span that overlaps an interval most, or
+    ``other``."""
     best, name = 0.0, "other"
-    for n, hs, he in trace["host"]:
-        if n == "traced_window" or he <= s or hs >= e:
+    for n, hs, he, _ in trace.get("program", []):
+        if not n.startswith(PHASE_PREFIX) or he <= s or hs >= e:
             continue
         ov = min(e, he) - max(s, hs)
         if ov > best:

@@ -57,14 +57,13 @@ class Stream:
     feed)."""
 
     def __init__(self, traffic: dict, vocab: int, chips: int, seed: int,
-                 shardings: dict, annotate=None):
+                 shardings: dict):
         self.traffic, self.vocab, self.seed = traffic, vocab, seed
         self.B = global_batch(traffic, chips)
         self.S = int(traffic["seq_len"])
         self.shardings = shardings
-        self.annotate = annotate
 
-    def _place(self, step: int) -> dict:
+    def sharded_batch(self, step, mesh, sharding):
         import jax
 
         out = {}
@@ -81,9 +80,3 @@ class Stream:
             out[name] = jax.make_array_from_callback(
                 (self.B, self.S), self.shardings[name], cb)
         return out
-
-    def sharded_batch(self, step, mesh, sharding):
-        if self.annotate is None:
-            return self._place(step)
-        with self.annotate("batch_placement"):
-            return self._place(step)

@@ -15,8 +15,15 @@ written: ``ckpt_every`` lies beyond any window and the loop never reaches
 its final save.
 
 With ``--trace 1`` the loop runs ``TRACE_STEPS`` more steps under the
-profiler after the window closes; the per-layer metrics read that trace and
-the window before it.
+profiler after the window closes; the per-layer metrics read that trace, the
+window before it, and what the trace gives by layer: device ms per step
+under each scope of the configuration's architecture (``layer_ms``) and
+device idle per step by the loop's phase (``idle_ms``). The traced line
+carries those two. Every run keeps the integer counters of the checked
+steps (``counts``), which depend on the seed alone and not on how many
+steps the window held; the line carries them (``counters``) and
+``routing.dropped_choices``, the program's own count of dropped choices
+over the checked steps, beside the reference's estimate.
 """
 
 from __future__ import annotations
@@ -31,7 +38,8 @@ import types
 
 import numpy as np
 
-from chipbench import compare, harness, program, traffic
+from chipbench import (compare, harness, layers, program, scopes,
+                       tracereduce, traffic)
 from chipbench.harness import BENCH_DIR, ROOT
 
 CHECKED_STEPS = 3
@@ -55,9 +63,10 @@ def reference_module(cfg: dict):
 class Loop:
     """The step function and the hook that ``train_loop`` calls.
 
-    ``step_fn`` calls the compiled step and keeps its output; the hook,
-    called before each step, takes the setup readings, opens and closes the
-    window and the traced steps, and records each window step's start.
+    ``step_fn`` calls the compiled step and keeps its output, and the
+    integer counters of the checked steps; the hook, called before each
+    step, takes the setup readings, opens and closes the window and the
+    traced steps, and records each window step's start.
     """
 
     def __init__(self, compiled, seconds: float, trace_dir, readings,
@@ -70,33 +79,24 @@ class Loop:
         self.compiles = compiles
         self.last = None
         self.metrics = []           # (loss, grad_norm) of every step
+        self.counters = {}          # step -> its integer counters
         self.starts = []            # window step starts
         self.t0 = self.t_end = None
         self.window_steps = 0
         self.compiles_in_window = None
         self.traced_from = None
-        self._span = None
-
-    def _open(self, name):
-        self._span = self.jax.profiler.TraceAnnotation(name)
-        self._span.__enter__()
-
-    def _close(self):
-        if self._span is not None:
-            self._span.__exit__(None, None, None)
-            self._span = None
 
     def step_fn(self, params, opt_state, batch):
-        with self.jax.profiler.TraceAnnotation("step_dispatch"):
-            out = self.compiled(params, opt_state, batch)
+        out = self.compiled(params, opt_state, batch)
         self.last = out
+        step = len(self.metrics)
+        if step < CHECKED_STEPS:
+            self.counters[step] = layers.int_counters(out[2])
         self.metrics.append((out[2]["loss"], out[2]["grad_norm"]))
-        self._open("loop_wait_and_log")
         return out
 
     def hook(self, step: int):
         now = time.perf_counter()
-        self._close()
         if step < SETUP_STEPS:
             self.readings(step, self.last)
             return
@@ -113,7 +113,7 @@ class Loop:
             self.traced_from = step
             self.jax.profiler.start_trace(self.trace_dir)
             self.trace_span = self.jax.profiler.TraceAnnotation(
-                "traced_window")
+                tracereduce.WINDOW_SPAN)
             self.trace_span.__enter__()
             return
         elif (self.traced_from is not None
@@ -156,7 +156,8 @@ class TrainCell:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
-        self.prog = program.Program(cfg, self.B, self.S, devices)
+        self.prog = program.Program(cfg, cell.arch, self.B, self.S,
+                                    devices)
         self.prog.check_layout(jax.eval_shape(
             lambda k: ref_mod.weights(cfg, k), ref_mod.seed_key(0)))
         self.init = self.prog.state_init(lambda k: ref_mod.weights(cfg, k))
@@ -180,8 +181,10 @@ class TrainCell:
 
     def drive(self, seed: int, seconds: float, trace_dir=None) -> dict:
         """Set-up steps and the window through ``train_loop``; returns the
-        loop, the program's readings of its checked steps, and the window's
-        failed steps. Frees the program's state before it returns."""
+        loop, the program's readings of its checked steps, the window's
+        failed steps and the integer counters of the checked steps
+        (step -> {name: value}). Frees the program's state before it
+        returns."""
         import jax
 
         from repro.ft.runner import FTConfig, train_loop
@@ -203,8 +206,7 @@ class TrainCell:
         loop = Loop(self.prog.step, seconds, trace_dir, readings,
                     self.compiles)
         stream = traffic.Stream(self.tr, self.cfg["vocab_size"], self.chips,
-                                seed, self.prog.batch_shardings,
-                                annotate=jax.profiler.TraceAnnotation)
+                                seed, self.prog.batch_shardings)
         ckpt_dir = os.path.join(OUT_DIR, "ckpt")
         shutil.rmtree(ckpt_dir, ignore_errors=True)  # nothing to resume
         params, opt_state = jax.block_until_ready(self.init(key_words))
@@ -225,11 +227,14 @@ class TrainCell:
         vals = np.asarray(jax.device_get(loop.metrics), np.float64)
         got["losses"] = [float(x) for x in vals[:CHECKED_STEPS, 0]]
         win = vals[SETUP_STEPS:SETUP_STEPS + loop.window_steps]
+        counts = {st: {k: int(v) for k, v in c.items()}
+                  for st, c in jax.device_get(loop.counters).items()}
         for a in jax.tree.leaves(loop.last):
             a.delete()
         loop.last = None
         return {"loop": loop, "got": got, "peak": int(peak), "t_init": t_init,
-                "failed": int(np.sum(~np.isfinite(win).all(axis=1)))}
+                "failed": int(np.sum(~np.isfinite(win).all(axis=1))),
+                "counts": counts}
 
     def reference(self, seed: int, precision: str = "float32",
                   fault=None) -> dict:
@@ -285,13 +290,18 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool,
         step_starts=loop.starts, t_end=loop.t_end,
         peaks=harness.peaks(dev0.device_kind),
         counters={"compiles_in_window": loop.compiles_in_window},
-        trace=None)
+        trace=None, layer_ms=None, idle_ms=None,
+        counts=[d["counts"][st] for st in range(CHECKED_STEPS)])
     device = {"platform": dev0.platform, "kind": dev0.device_kind,
               "count": tc.chips, "memory_peak_bytes": d["peak"]}
     breakdown = None
+    if all("moe_dropped" in c for c in run_ns.counts):
+        routing["dropped_choices"] = sum(c["moe_dropped"]
+                                         for c in run_ns.counts)
+    extra = {"routing": routing, "setup_phases": phases,
+             "counters": [dict(c, step=st)
+                          for st, c in enumerate(run_ns.counts)]}
     if trace:
-        from chipbench import tracereduce
-
         run_ns.trace = tracereduce.load(trace_dir)
         lo, hi = tracereduce.window(run_ns.trace)
         busy = [tracereduce.busy_ns(ev, lo, hi)
@@ -307,6 +317,15 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool,
             json.dump({"structure": tracereduce.structure(trace_dir),
                        "events": run_ns.trace, "breakdown": breakdown}, f)
         shutil.rmtree(trace_dir, ignore_errors=True)
+        # Device time by the architecture's scopes, read with the op->scope
+        # map of the compiled step (as_text() is None where the backend
+        # keeps no HLO text); idle time by loop phase.
+        names = cell.arch.SCOPES
+        run_ns.layer_ms = scopes.layer_ms(
+            run_ns.trace, scopes.op_scopes(tc.prog.step.as_text() or "",
+                                           names), names)
+        run_ns.idle_ms = scopes.idle_by_phase(run_ns.trace)
+        extra.update(layer_ms=run_ns.layer_ms, idle_ms=run_ns.idle_ms)
     metrics = harness.read_metrics(
         cell, cell.per_layer if trace else cell.end_to_end, run_ns)
 
@@ -321,8 +340,6 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool,
         print(f"check {name} {c['value']!r} limit {c['limit']!r}",
               file=sys.stderr)
     line = harness.result_line(correct, loop.window_steps, failed, metrics,
-                               device, checks, breakdown,
-                               extra={"routing": routing,
-                                      "setup_phases": phases})
+                               device, checks, breakdown, extra=extra)
     print(line, flush=True)
     return 0
