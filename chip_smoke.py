@@ -3,12 +3,19 @@
 
     python3 chip_smoke.py              # one chip
     python3 chip_smoke.py --chips 4    # expert parallelism over four chips
+    python3 chip_smoke.py --model moonlight   # Moonlight's step, one chip
 
 One chip: trains granite-moe-3b-a800m at its published widths, its depth
 cut to 4 layers, for 5 steps through ``repro.launch.train.main`` on a 1x1
 mesh (the fixed-capacity EP path); then runs one full-width MoE layer
 through the EP path twice, with einsum experts and with the Pallas kernels,
 and compares both with a float32 reference.
+
+Moonlight: one training step of Moonlight-16B-A3B (MLA, shared experts,
+sigmoid routing with its bias, a leading dense layer) at its published
+widths, chip 0's share of an eight-chip expert-parallel group (8 of 64
+experts, an eighth of the vocabulary), 1 dense + 5 MoE layers, through
+``repro.launch.train.main``.
 
 Four chips: trains the same model on a 1x4 expert-parallel mesh (12 experts
 per chip) twice from the same seed and data, once with the all_to_all
@@ -35,6 +42,8 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 ARCH = "granite-moe-3b-a800m"
 # Published widths; depth is the only cut (4 of 32 layers fit one chip).
 MODEL_ARGS = ["--arch", ARCH, "--layers", "4", "--seq", "2048"]
+MOONLIGHT_ARGS = ["--arch", "moonlight-16b-a3b", "--layers", "6",
+                  "--expert-share", "8", "--seq", "4096"]
 # Tokens through the MoE layer check: C = 1000 rows per expert, not a
 # multiple of the kernels' 128-row block.
 MOE_TOKENS = 1000
@@ -65,11 +74,12 @@ def require_tpu(chips: int):
     return devs
 
 
-def train(steps: int, extra: list[str]) -> list[dict]:
+def train(steps: int, extra: list[str], model_args=None) -> list[dict]:
     """Train through the trainer's entry point; return its per-step log."""
     from repro.launch import train as T
 
-    argv = MODEL_ARGS + ["--steps", str(steps), "--ckpt-dir", CKPT_DIR,
+    argv = (model_args or MODEL_ARGS) + [
+        "--steps", str(steps), "--ckpt-dir", CKPT_DIR,
                          "--ckpt-every", str(steps)] + extra
     print("train.main", " ".join(argv), flush=True)
     shutil.rmtree(CKPT_DIR, ignore_errors=True)
@@ -143,6 +153,11 @@ def one_chip(devs) -> None:
     moe_layer_check(get_config(ARCH), MOE_TOKENS)
 
 
+def moonlight(devs) -> None:
+    train(1, ["--mesh", "1x1", "--global-batch", "2"], MOONLIGHT_ARGS)
+    print_peak_memory(devs[:1])
+
+
 def four_chips(devs) -> None:
     logs = {mode: train(3, ["--mesh", "1x4", "--mode", "ep_dp",
                             "--ep-mode", mode, "--global-batch", "4"])
@@ -166,7 +181,11 @@ def four_chips(devs) -> None:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--model", choices=("granite", "moonlight"),
+                    default="granite")
     args = ap.parse_args(argv)
+    if args.model == "moonlight" and args.chips != 1:
+        ap.error("--model moonlight runs on one chip")
 
     devs = require_tpu(args.chips)
     sys.path.insert(0, os.path.join(REPO, "src"))
@@ -174,7 +193,9 @@ def main(argv=None) -> int:
 
     enable_compile_cache()
     print(f"devices: {len(devs)} x {devs[0].device_kind}", flush=True)
-    (four_chips if args.chips == 4 else one_chip)(devs)
+    phase = (four_chips if args.chips == 4
+             else moonlight if args.model == "moonlight" else one_chip)
+    phase(devs)
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,
         "count": len(devs)}}))

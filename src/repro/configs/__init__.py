@@ -16,6 +16,7 @@ ARCHS = [
     "hubert-xlarge",
     "mamba2-1_3b",
     "internvl2-26b",
+    "moonlight-16b-a3b",
 ]
 
 _ALIASES = {

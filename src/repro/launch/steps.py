@@ -7,8 +7,12 @@ the model code mesh-agnostic (the paper's low-intrusion integration).
 
 The train step's metrics are ``loss``, ``grad_norm``, ``lr`` and, for a
 fixed-capacity MoE model, the int32 counters ``moe_routed``, ``moe_dropped``
-and ``moe_slots`` (``models.moe.routing_counts``) summed over layers and
-chips.
+and ``moe_slots`` (``models.moe.routing_counts``; ``moe_not_held`` too where
+a layer holds a share of the experts) summed over layers and chips. With a
+sigmoid router the step also reports ``moe_balance_loss`` (part of
+``loss``) and moves each MoE layer's router bias by its load
+(``models.moe.bias_step``) after the optimizer, which leaves the bias alone;
+the bias's slot of Adam's first moment keeps its running excess load.
 """
 
 from __future__ import annotations
@@ -22,6 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.models import model as M
+from repro.models.moe import bias_step
 from repro.optim import adamw
 from repro.parallel.ctx import (activation_sharding, flash_decode_context,
                                 head_sharding, moe_impl_context)
@@ -117,6 +122,15 @@ def make_steps(cfg, mesh, *, opt: Optional[adamw.OptConfig] = None,
                 g, rules.param_spec(path, g.shape)), grads)
         params2, opt_state2, metrics = adamw.apply_updates(
             params, grads, opt_state, opt, grad_transform=grad_transform)
+        if "moe_load" in counts:
+            counts = dict(counts)
+            moment = opt_state2["m"]["blocks"]["moe"]
+            bias, moment["router_bias"] = bias_step(
+                opt_state2["master"]["blocks"]["moe"]["router_bias"],
+                moment["router_bias"], counts.pop("moe_load"),
+                cfg.moe.bias_speed, opt.betas[0])
+            for tree in (params2, opt_state2["master"]):
+                tree["blocks"]["moe"]["router_bias"] = bias
         metrics["loss"] = lv
         # The MoE layers' counters (moe_routed, moe_dropped, moe_slots),
         # summed over layers and chips; absent where the MoE path records

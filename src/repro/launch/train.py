@@ -15,6 +15,12 @@ On one TPU chip, at a model's published widths with its depth cut to fit:
     PYTHONPATH=src python -m repro.launch.train \
         --arch granite-moe-3b-a800m --layers 4 --mesh 1x1 \
         --global-batch 2 --seq 2048 --steps 5
+
+or with one chip's share of an eight-chip expert-parallel group:
+
+    PYTHONPATH=src python -m repro.launch.train \
+        --arch moonlight-16b-a3b --layers 6 --expert-share 8 --mesh 1x1 \
+        --global-batch 2 --seq 4096 --steps 5
 """
 
 from __future__ import annotations
@@ -64,6 +70,11 @@ def main(argv=None):
     ap.add_argument("--layers", type=int, default=0,
                     help="cut the model's depth to N layers (0 = the "
                          "config's own); widths are never changed")
+    ap.add_argument("--expert-share", type=int, default=1, metavar="N",
+                    help="hold chip 0's share of an N-chip expert-parallel "
+                         "group: the first 1/N of each MoE layer's experts "
+                         "(the router still routes over all) and of the "
+                         "vocabulary")
     ap.add_argument("--mesh", default="2x4",
                     help="dataxmodel (or podxdataxmodel)")
     ap.add_argument("--mode", default="tp_sp",
@@ -125,6 +136,15 @@ def main(argv=None):
     overrides = {"n_layers": args.layers} if args.layers else {}
     cfg = (get_smoke_config if args.smoke else get_config)(
         args.arch, **overrides)
+    if args.expert_share > 1:
+        import dataclasses
+        n = args.expert_share
+        if cfg.family != "moe" or cfg.moe.e_total % n or cfg.vocab % n:
+            ap.error(f"--expert-share {n} does not divide {args.arch}'s "
+                     "experts and vocabulary")
+        cfg = dataclasses.replace(
+            cfg, vocab=cfg.vocab // n,
+            moe=dataclasses.replace(cfg.moe, n_held=cfg.moe.e_total // n))
     if cfg.family == "moe":
         # Pad experts so E % model-axis == 0 (router never selects padding).
         import dataclasses

@@ -51,9 +51,10 @@ def nonparam_ln(x, eps=1e-5):
     return layer_norm(x, None, None, eps)
 
 
-def apply_norm(kind: str, x, p, name: str):
+def apply_norm(kind: str, x, p, name: str, eps: float = 1e-6):
+    """``eps`` is the RMSNorm's; the LayerNorms keep their own."""
     if kind == "rmsnorm":
-        return rms_norm(x, p[name])
+        return rms_norm(x, p[name], eps)
     if kind == "layernorm":
         return layer_norm(x, p[name], p.get(name + "_b"))
     if kind == "nonparam_ln":
@@ -104,12 +105,13 @@ def blockwise_attention(q, k, v, *, causal: bool, q_offset,
                         scale: Optional[float] = None):
     """Online-softmax attention, O(S·block) memory.
 
-    q: [B, Sq, H, hd]; k/v: [B, Sk, K, hd] with K | H (GQA: kv repeated).
+    q/k: [B, S, H or K, hd]; v: [B, Sk, K, hd_v], K | H (GQA: kv repeated);
+    the output has v's head size.
     ``q_offset`` is the absolute position of q[0] relative to k[0] (decode:
     cache_len; self-attn: 0). ``sliding_window`` masks keys older than W.
     """
     B, Sq, H, hd = q.shape
-    Sk, K = k.shape[1], k.shape[2]
+    Sk, K, hd_v = k.shape[1], k.shape[2], v.shape[-1]
     if K != H:
         rep = H // K
         k = jnp.repeat(k, rep, axis=2)
@@ -121,7 +123,7 @@ def blockwise_attention(q, k, v, *, causal: bool, q_offset,
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     kb = k.reshape(B, nb, block, H, hd)
-    vb = v.reshape(B, nb, block, H, hd)
+    vb = v.reshape(B, nb, block, H, hd_v)
 
     q_pos = q_offset + jnp.arange(Sq)                        # [Sq]
 
@@ -146,7 +148,7 @@ def blockwise_attention(q, k, v, *, causal: bool, q_offset,
 
     m0 = jnp.full((B, H, Sq), -1e30, dtype=jnp.float32)
     l0 = jnp.zeros((B, H, Sq), dtype=jnp.float32)
-    o0 = jnp.zeros((B, Sq, H, hd), dtype=jnp.float32)
+    o0 = jnp.zeros((B, Sq, H, hd_v), dtype=jnp.float32)
     # checkpoint per KV block: the backward recomputes each block's scores
     # instead of saving [nb, B, H, Sq, block] fp32 probs — this is what
     # makes the attention actually flash-like in memory on the bwd pass.
@@ -182,7 +184,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *,
     s = jnp.where(mask[:, None, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v_cache.dtype), v_cache)
-    return o.reshape(B, 1, H, hd)
+    return o.reshape(B, 1, H, v_cache.shape[-1])
 
 
 def _ring_decode_attention(q, k_cache, v_cache, n_tokens, W):
@@ -333,6 +335,96 @@ def attention(p, x, *, n_heads, n_kv_heads, head_dim, rope_theta,
 
     out = jnp.einsum("bse,ed->bsd", o.reshape(B, S, n_heads * head_dim),
                      p["wo"].astype(compute_dtype))
+    return out, new_cache
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2/V3), without the query low-rank path
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Keys and values come up from a ``kv_lora_rank`` latent of each token;
+    a ``qk_rope_dim`` rotary key is shared by all heads. Queries come
+    straight from the hidden state (no query low-rank path)."""
+    kv_lora_rank: int
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
+
+
+def init_mla(key, d_model, n_heads, m: MLAConfig, dtype=jnp.float32):
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    r, hv = m.kv_lora_rank, n_heads * m.v_head_dim
+    std = d_model ** -0.5
+    return {
+        "wq": jax.random.normal(k1, (d_model, n_heads * m.qk_dim), dtype)
+        * std,
+        "wkv_a": jax.random.normal(k2, (d_model, r + m.qk_rope_dim), dtype)
+        * std,
+        "kv_norm": jnp.zeros((r,), jnp.float32),
+        "wkv_b": jax.random.normal(
+            k3, (r, n_heads * (m.qk_nope_dim + m.v_head_dim)), dtype)
+        * r ** -0.5,
+        "wo": jax.random.normal(k4, (hv, d_model), dtype) * hv ** -0.5,
+    }
+
+
+@jax.named_scope("mla")
+def mla_attention(p, x, m: MLAConfig, *, n_heads, rope_theta, eps,
+                  block=1024, cache=None):
+    """Returns (out, new_cache).
+
+    ``q = x W_q`` ([nope | rope] per head); ``[c | k_rope] = x W_kv_a``;
+    ``[k_nope | v] = RMSNorm(c) W_kv_b`` per head; RoPE on ``q_rope`` and on
+    the one shared ``k_rope``; causal softmax of ``[q_nope, q_rope] .
+    [k_nope, k_rope] / sqrt(qk_dim)``; ``o = (p v) W_o``. Rotary halves are
+    split as in :func:`apply_rope`. ``cache`` (uniform length only) holds
+    the heads' full keys and values, as a GQA cache does. The projections,
+    the latent norm and the rotary embedding run under the ``mla`` scope,
+    the causal softmax attention under ``mla/attention``."""
+    B, S, _ = x.shape
+    dt = x.dtype
+    H, nope, r = n_heads, m.qk_nope_dim, m.kv_lora_rank
+    q = jnp.einsum("bsd,de->bse", x, p["wq"].astype(dt)).reshape(
+        B, S, H, m.qk_dim)
+    kv_a = jnp.einsum("bsd,de->bse", x, p["wkv_a"].astype(dt))
+    c = rms_norm(kv_a[..., :r], p["kv_norm"], eps)
+    kv = jnp.einsum("bsr,re->bse", c, p["wkv_b"].astype(dt)).reshape(
+        B, S, H, nope + m.v_head_dim)
+    start = 0 if cache is None else jnp.asarray(cache["len"])
+    if jnp.ndim(start):
+        raise NotImplementedError("MLA caches take one length per batch")
+    cos, sin = rope_tables(jnp.broadcast_to(start + jnp.arange(S), (B, S)),
+                           m.qk_rope_dim, rope_theta)
+    q = jnp.concatenate([q[..., :nope], apply_rope(q[..., nope:], cos, sin)],
+                        -1)
+    k_rope = apply_rope(kv_a[..., None, r:], cos, sin)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_rope, (B, S, H, m.qk_rope_dim))],
+        -1)
+    v = kv[..., nope:]
+    scale = m.qk_dim ** -0.5
+    new_cache = None
+    if cache is not None:
+        kc = jax.lax.dynamic_update_slice(cache["k"], k, (0, start, 0, 0))
+        vc = jax.lax.dynamic_update_slice(cache["v"], v, (0, start, 0, 0))
+        new_cache = {"k": kc, "v": vc, "len": cache["len"] + S}
+    # The attention proper, the blockwise kernel GQA runs too, under the
+    # ``attention`` scope within ``mla``.
+    with jax.named_scope("attention"):
+        if cache is not None and S == 1:
+            o = decode_attention(q, kc, vc, cache["len"] + 1, scale=scale)
+        else:   # training, or a prefill into an empty cache
+            o = blockwise_attention(q, k, v, causal=True, q_offset=0,
+                                    block=block, scale=scale)
+    out = jnp.einsum("bse,ed->bsd", o.reshape(B, S, H * m.v_head_dim),
+                     p["wo"].astype(dt))
     return out, new_cache
 
 

@@ -14,7 +14,13 @@ The embedding and the unembedding with its cross-entropy run under the
 ``embed`` and ``unembed_ce`` named scopes. The MoE layer's counters (see
 ``models.moe.routing_counts``) leave the layer scan as its outputs, summed
 over layers: ``loss_fn(..., with_counts=True)`` returns them beside the
-loss.
+loss. A sigmoid router's ``moe_load`` stays per layer ([L, E], for the
+train step's bias update) and its ``moe_balance_loss``, summed over layers,
+joins the loss.
+
+A MoE stack may lead with ``n_dense_layers`` dense layers (DeepSeek-V3's
+``first_k_dense_replace``), kept apart under ``dense_blocks`` and run before
+the scanned MoE layers; their FFN runs under the ``dense_ffn`` scope.
 """
 
 from __future__ import annotations
@@ -31,6 +37,7 @@ from repro.parallel.ctx import (constrain_activation, current_moe_impl,
                                 moe_counts_scope)
 
 from . import layers as L
+from .layers import MLAConfig
 from .moe import MoEConfig, init_moe, moe_grouped
 from .rglru import init_rglru, rglru_block
 from .ssm import SSMConfig, init_ssm, ssm_forward
@@ -70,6 +77,9 @@ class ModelConfig:
     remat_policy: str = "full"
     scan_layers: bool = True      # False → unrolled python loop (cost probes)
     attn_block: int = 1024        # KV block for blockwise attention
+    norm_eps: float = 1e-6        # RMSNorm epsilon
+    mla: Optional[MLAConfig] = None   # latent attention in place of GQA
+    n_dense_layers: int = 0       # leading dense layers of a MoE stack
 
     @property
     def hd(self) -> int:
@@ -87,7 +97,8 @@ class ModelConfig:
         if self.family in ("dense", "audio", "vlm"):
             return ["attn"] * self.n_layers
         if self.family == "moe":
-            return ["attn_moe"] * self.n_layers
+            return (["attn"] * self.n_dense_layers
+                    + ["attn_moe"] * (self.n_layers - self.n_dense_layers))
         if self.family == "ssm":
             return ["ssm"] * self.n_layers
         if self.family == "hybrid":
@@ -105,7 +116,12 @@ class ModelConfig:
         if not self.tie_embeddings:
             n += d * V
         for t in self.layer_types():
-            if t in ("attn", "attn_moe", "local_attn"):
+            if t in ("attn", "attn_moe", "local_attn") and self.mla:
+                m, H = self.mla, self.n_heads
+                n += d * H * m.qk_dim + d * (m.kv_lora_rank + m.qk_rope_dim)
+                n += m.kv_lora_rank * H * (m.qk_nope_dim + m.v_head_dim)
+                n += H * m.v_head_dim * d
+            elif t in ("attn", "attn_moe", "local_attn"):
                 n += d * (self.n_heads + 2 * self.n_kv_heads) * self.hd
                 n += self.n_heads * self.hd * d
             if t == "attn":
@@ -114,7 +130,8 @@ class ModelConfig:
                 n += (3 if self.act in ("swiglu", "geglu") else 2) * d * f
             if t == "attn_moe":
                 m = self.moe
-                n += d * m.e_total + m.e_total * 3 * d * m.d_expert
+                n += d * m.e_total + m.e_held * 3 * d * m.d_expert
+                n += m.n_shared * 3 * d * m.d_expert
             if t == "ssm":
                 s = self.ssm
                 d_in = s.expand * d
@@ -130,9 +147,10 @@ class ModelConfig:
         if self.family != "moe":
             return self.param_count()
         m = self.moe
+        n_moe = self.n_layers - self.n_dense_layers
         full = self.param_count()
-        expert_p = self.n_layers * m.e_total * 3 * self.d_model * m.d_expert
-        active_e = self.n_layers * m.top_k * 3 * self.d_model * m.d_expert
+        expert_p = n_moe * m.e_held * 3 * self.d_model * m.d_expert
+        active_e = n_moe * m.top_k * 3 * self.d_model * m.d_expert
         return full - expert_p + active_e
 
 
@@ -156,7 +174,9 @@ def _init_block(cfg: ModelConfig, btype: str, key):
     dt = jnp.float32
     ks = jax.random.split(key, 4)
     p: dict = {}
-    if btype in ("attn", "attn_moe", "local_attn"):
+    if btype in ("attn", "attn_moe", "local_attn") and cfg.mla:
+        p["attn"] = L.init_mla(ks[0], cfg.d_model, cfg.n_heads, cfg.mla, dt)
+    elif btype in ("attn", "attn_moe", "local_attn"):
         p["attn"] = L.init_attention(ks[0], cfg.d_model, cfg.n_heads,
                                      cfg.n_kv_heads, cfg.hd, cfg.qkv_bias, dt)
     if btype in ("attn", "local_attn"):
@@ -206,10 +226,14 @@ def init_params(cfg: ModelConfig, key) -> dict:
             _init_block(cfg, types[i], lkeys[i])
             for i in range(n_super * pat, cfg.n_layers)]
     else:
-        params["blocks"] = jax.tree.map(
-            lambda *xs: jnp.stack(xs),
-            *[_init_block(cfg, types[i], lkeys[i])
-              for i in range(cfg.n_layers)])
+        def stack(lo, hi):
+            return jax.tree.map(
+                lambda *xs: jnp.stack(xs),
+                *[_init_block(cfg, types[i], lkeys[i]) for i in range(lo, hi)])
+
+        if cfg.n_dense_layers:
+            params["dense_blocks"] = stack(0, cfg.n_dense_layers)
+        params["blocks"] = stack(cfg.n_dense_layers, cfg.n_layers)
     return params
 
 
@@ -219,14 +243,19 @@ def init_params(cfg: ModelConfig, key) -> dict:
 
 
 def _norm(cfg, p, x, which):
-    return L.apply_norm(cfg.norm, x, p, which)
+    return L.apply_norm(cfg.norm, x, p, which, cfg.norm_eps)
 
 
 def block_apply(cfg: ModelConfig, btype: str, p, x, cache=None,
                 moe_impl: Optional[Callable] = None):
     """One residual block. Returns (x, new_cache)."""
     new_cache = None
-    if btype in ("attn", "attn_moe", "local_attn"):
+    if btype in ("attn", "attn_moe", "local_attn") and cfg.mla:
+        a, new_cache = L.mla_attention(
+            p["attn"], _norm(cfg, p, x, "ln1"), cfg.mla,
+            n_heads=cfg.n_heads, rope_theta=cfg.rope_theta, eps=cfg.norm_eps,
+            block=cfg.attn_block, cache=cache)
+    elif btype in ("attn", "attn_moe", "local_attn"):
         window = cfg.sliding_window if btype == "local_attn" else (
             cfg.sliding_window if cfg.family != "hybrid" else 0)
         a, new_cache = L.attention(
@@ -234,16 +263,23 @@ def block_apply(cfg: ModelConfig, btype: str, p, x, cache=None,
             n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd,
             rope_theta=cfg.rope_theta, causal=cfg.causal,
             sliding_window=window, block=cfg.attn_block, cache=cache)
+    if btype in ("attn", "attn_moe", "local_attn"):
         x = x + a
         h = _norm(cfg, p, x, "ln2")
         if btype == "attn_moe":
             impl = (moe_impl or current_moe_impl()
                     or partial(moe_grouped, act=cfg.act))
             moe_out = impl(p["moe"], h, cfg.moe)
+            if cfg.moe.n_shared:
+                with jax.named_scope("moe/shared_expert"):
+                    moe_out = moe_out + L.mlp(p["moe"]["shared"], h, cfg.act)
             if cfg.remat_policy == "save_moe":
                 from jax.ad_checkpoint import checkpoint_name
                 moe_out = checkpoint_name(moe_out, "moe_out")
             x = x + moe_out
+        elif cfg.n_dense_layers:
+            with jax.named_scope("dense_ffn"):
+                x = x + L.mlp(p["mlp"], h, cfg.act)
         else:
             x = x + L.mlp(p["mlp"], h, cfg.act)
     elif btype == "ssm":
@@ -282,6 +318,16 @@ def embed_inputs(cfg: ModelConfig, params, batch) -> jnp.ndarray:
 def _sum_counts(counts: list) -> dict:
     """Key-wise sum of MoE counter dicts ({} for none)."""
     return {k: sum(c[k] for c in counts) for k in counts[0]} if counts else {}
+
+
+# Router statistics kept per layer, not summed over the stack.
+PER_LAYER = ("moe_load",)
+
+
+def _layer_totals(counts: dict) -> dict:
+    """Counters stacked over layers ([L, ...]) summed to totals; those of
+    ``PER_LAYER`` keep their layer axis."""
+    return {k: v if k in PER_LAYER else jnp.sum(v) for k, v in counts.items()}
 
 
 def _run_stack(cfg: ModelConfig, params, x, caches=None,
@@ -332,7 +378,25 @@ def _run_stack(cfg: ModelConfig, params, x, caches=None,
                       else {"super": new_sup, "tail": new_tail})
         return x, new_caches, {}
 
-    btype = types[0]
+    n_dense = cfg.n_dense_layers
+    if n_dense:
+        # The leading dense layers, unrolled; their caches are the first
+        # n_dense of the stacked caches (attention caches of one shape).
+        def dense(x, p, c):
+            x, nc = block_apply(cfg, "attn", p, x, c)
+            return constrain_activation(x), nc
+
+        dense = jax.checkpoint(dense) if cfg.remat else dense
+        dense_caches = []
+        for i in range(n_dense):
+            x, nc = dense(
+                x, jax.tree.map(lambda a: a[i], params["dense_blocks"]),
+                None if caches is None
+                else jax.tree.map(lambda a: a[i], caches))
+            dense_caches.append(nc)
+        if caches is not None:
+            caches = jax.tree.map(lambda a: a[n_dense:], caches)
+    btype = types[n_dense]
 
     def body(x, inp):
         ps, cs = inp
@@ -353,11 +417,15 @@ def _run_stack(cfg: ModelConfig, params, x, caches=None,
     if cfg.scan_layers:
         x, (new_caches, counts) = jax.lax.scan(
             fn, x, (params["blocks"], caches))
-        return x, new_caches, {k: jnp.sum(v) for k, v in counts.items()}
+        if n_dense and new_caches is not None:
+            new_caches = jax.tree.map(
+                lambda a, *d: jnp.concatenate([jnp.stack(d), a]),
+                new_caches, *dense_caches)
+        return x, new_caches, _layer_totals(counts)
     # Unrolled loop (used by the dry-run cost probes: XLA's HloCostAnalysis
     # counts while bodies once, so scanned stacks under-report flops).
     ncs, counts = [], []
-    for i in range(cfg.n_layers):
+    for i in range(cfg.n_layers - cfg.n_dense_layers):
         ps = jax.tree.map(lambda a: a[i], params["blocks"])
         cs = (None if caches is None
               else jax.tree.map(lambda a: a[i], caches))
@@ -365,9 +433,11 @@ def _run_stack(cfg: ModelConfig, params, x, caches=None,
         ncs.append(nc)
         counts.append(c)
     new_caches = (None if caches is None
-                  else jax.tree.map(lambda *a: jnp.stack(a), *ncs))
-    return x, new_caches, {k: jnp.sum(v)
-                           for k, v in _sum_counts(counts).items()}
+                  else jax.tree.map(lambda *a: jnp.stack(a),
+                                    *(dense_caches if n_dense else []), *ncs))
+    return x, new_caches, _layer_totals(
+        {k: jnp.stack([c[k] for c in counts]) for k in counts[0]}
+        if counts and counts[0] else {})
 
 
 def forward(cfg: ModelConfig, params, batch, moe_impl=None):
@@ -420,6 +490,8 @@ def loss_fn(cfg: ModelConfig, params, batch, moe_impl=None,
         (nll, cnt), _ = jax.lax.scan(jax.checkpoint(chunk_body),
                                      (0.0, 0.0), (xs, ls))
         loss = nll / jnp.maximum(cnt, 1.0)
+    if "moe_balance_loss" in counts:
+        loss = loss + counts["moe_balance_loss"]
     return (loss, counts) if with_counts else loss
 
 
@@ -429,7 +501,7 @@ def final_hidden(cfg: ModelConfig, params, batch, moe_impl=None):
     x = constrain_activation(embed_inputs(cfg, params, batch))
     x, _, counts = _run_stack(cfg, params, x, None, moe_impl)
     if cfg.norm != "nonparam_ln":
-        x = L.apply_norm(cfg.norm, x, params, "ln_f")
+        x = L.apply_norm(cfg.norm, x, params, "ln_f", cfg.norm_eps)
     else:
         x = L.nonparam_ln(x)
     if cfg.family == "vlm" and "patches" in batch:
@@ -446,6 +518,11 @@ def _block_cache(cfg: ModelConfig, btype: str, B: int, max_len: int,
                  per_slot_len: bool = False):
     dt = cfg.compute_dtype
     zlen = (jnp.zeros((B,), jnp.int32) if per_slot_len else jnp.int32(0))
+    if btype in ("attn", "attn_moe") and cfg.mla:
+        m = cfg.mla
+        return {"k": jnp.zeros((B, max_len, cfg.n_heads, m.qk_dim), dt),
+                "v": jnp.zeros((B, max_len, cfg.n_heads, m.v_head_dim), dt),
+                "len": zlen}
     if btype in ("attn", "attn_moe"):
         shp = (B, max_len, cfg.n_kv_heads, cfg.hd)
         return {"k": jnp.zeros(shp, dt), "v": jnp.zeros(shp, dt),
@@ -495,7 +572,7 @@ def decode_step(cfg: ModelConfig, params, token, cache, moe_impl=None):
         x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
     x, new_cache, _ = _run_stack(cfg, params, x, cache, moe_impl)
     if cfg.norm != "nonparam_ln":
-        x = L.apply_norm(cfg.norm, x, params, "ln_f")
+        x = L.apply_norm(cfg.norm, x, params, "ln_f", cfg.norm_eps)
     else:
         x = L.nonparam_ln(x)
     unembed = (params["embed"].T if cfg.tie_embeddings
@@ -516,7 +593,7 @@ def prefill(cfg: ModelConfig, params, batch, max_len: int, moe_impl=None):
     x = embed_inputs(cfg, params, batch)
     x, new_cache, _ = _run_stack(cfg, params, x, cache, moe_impl)
     if cfg.norm != "nonparam_ln":
-        x = L.apply_norm(cfg.norm, x, params, "ln_f")
+        x = L.apply_norm(cfg.norm, x, params, "ln_f", cfg.norm_eps)
     else:
         x = L.nonparam_ln(x)
     unembed = (params["embed"].T if cfg.tie_embeddings

@@ -47,23 +47,52 @@ class MoEConfig:
     router_jitter: float = 0.0
     # Experts padded up so E % ep == 0 (router never selects padding).
     n_padding_experts: int = 0
+    # "softmax": top-k of the softmax, renormalised. "sigmoid" (DeepSeek-V3,
+    # no group limit): top-k of sigmoid scores plus the selection bias
+    # ``router_bias``, weights the chosen unbiased scores renormalised and
+    # times ``routed_scale``; the bias moves ``bias_speed`` a step against
+    # each expert's excess load (``bias_step``), and a sequence-wise balance
+    # loss of weight ``balance_alpha`` joins the loss.
+    score: str = "softmax"
+    routed_scale: float = 1.0
+    bias_speed: float = 0.0
+    balance_alpha: float = 0.0
+    # Shared experts of width d_expert that every token passes (one SwiGLU
+    # of their summed width).
+    n_shared: int = 0
+    # The experts this layer holds, [held_first, held_first + n_held) of the
+    # router's e_total (one chip's share of an expert-parallel group); the
+    # router still routes over all of them. 0 holds all.
+    n_held: int = 0
+    held_first: int = 0
 
     @property
     def e_total(self) -> int:
         return self.n_experts + self.n_padding_experts
+
+    @property
+    def e_held(self) -> int:
+        return self.n_held or self.e_total
 
 
 def init_moe(key, d_model: int, mc: MoEConfig, dtype=jnp.float32):
     k1, k2, k3 = jax.random.split(key, 3)
     E = mc.e_total
     std = d_model ** -0.5
-    return {
+    p = {
         "router": jax.random.normal(k1, (d_model, E), jnp.float32) * std,
-        "w_in": jax.random.normal(k2, (E, d_model, 2 * mc.d_expert), dtype)
-        * std,
-        "w_down": jax.random.normal(k3, (E, mc.d_expert, d_model), dtype)
-        * mc.d_expert ** -0.5,
+        "w_in": jax.random.normal(
+            k2, (mc.e_held, d_model, 2 * mc.d_expert), dtype) * std,
+        "w_down": jax.random.normal(k3, (mc.e_held, mc.d_expert, d_model),
+                                    dtype) * mc.d_expert ** -0.5,
     }
+    if mc.score == "sigmoid":
+        p["router_bias"] = jnp.zeros((E,), jnp.float32)
+    if mc.n_shared:
+        from .layers import init_mlp
+        p["shared"] = init_mlp(jax.random.fold_in(key, 3), d_model,
+                               mc.n_shared * mc.d_expert, "swiglu", dtype)
+    return p
 
 
 def router_topk(p_router, x, mc: MoEConfig):
@@ -79,6 +108,56 @@ def router_topk(p_router, x, mc: MoEConfig):
     top_p, top_i = jax.lax.top_k(probs, mc.top_k)
     top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     return top_p, top_i
+
+
+def route(params, x, mc: MoEConfig):
+    """The router's choices for x [T, d]: ``(top_p [T, k], top_i [T, k],
+    scores)``, ``scores`` the sigmoid scores [T, E] (None for softmax)."""
+    if mc.score == "softmax":
+        return router_topk(params["router"], x, mc) + (None,)
+    if mc.score != "sigmoid":
+        raise ValueError(f"unknown router score {mc.score!r}")
+    s = jax.nn.sigmoid(jnp.einsum("td,de->te", x.astype(jnp.float32),
+                                  params["router"]))
+    bias = jax.lax.stop_gradient(params["router_bias"])
+    _, top_i = jax.lax.top_k(s + bias, mc.top_k)
+    top_s = jnp.take_along_axis(s, top_i, axis=-1)
+    top_p = top_s / jnp.sum(top_s, axis=-1, keepdims=True) * mc.routed_scale
+    return top_p, top_i, s
+
+
+def balance_stats(scores, top_i, rows: int):
+    """Per sequence (``rows`` equal runs of tokens): each expert's choices
+    ``[rows, E]`` and the sum over its tokens of the scores normalised over
+    the experts ``[rows, E]``."""
+    T, E = scores.shape
+    chosen = jnp.sum(jax.nn.one_hot(top_i, E, dtype=jnp.float32), axis=1)
+    share = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    return (chosen.reshape(rows, T // rows, E).sum(1),
+            share.reshape(rows, T // rows, E).sum(1))
+
+
+def router_stats(chosen, share, seq_len: int, mc: MoEConfig) -> dict:
+    """One layer's ``moe_load`` (choices per expert over the batch, [E]) and
+    ``moe_balance_loss``: DeepSeek-V3's sequence-wise balance loss,
+    ``alpha * sum_e f_e P_e`` with ``f_e = E / (k S) * choices`` and ``P_e``
+    the mean normalised score over the sequence, averaged over sequences."""
+    f = chosen * (mc.e_total / (mc.top_k * seq_len))
+    loss = mc.balance_alpha * jnp.mean(jnp.sum(f * share / seq_len, -1))
+    return {"moe_load": jnp.sum(chosen, 0), "moe_balance_loss": loss}
+
+
+@jax.named_scope("moe/router")
+def bias_step(bias, moment, load, speed: float, beta: float):
+    """The aux-loss-free balance: each expert's bias moves ``speed`` against
+    its load's excess over the mean (``load`` [..., E]). The bias's first
+    moment ``moment`` keeps the running mean of that excess, weighted
+    ``beta`` as Adam's first moment weights a gradient: the direction the
+    bias steps against, kept where a trained leaf keeps its gradient's.
+    Returns the new bias and moment."""
+    excess = load - jnp.mean(load, axis=-1, keepdims=True)
+    return (bias - speed * jnp.sign(excess),
+            beta * moment + (1 - beta) * excess)
 
 
 def load_balance_loss(p_router, x, mc: MoEConfig):
@@ -108,12 +187,20 @@ def capacity(tokens: int, mc: MoEConfig, ep: int = 1) -> int:
     return max(ep, ((c + ep - 1) // ep) * ep)
 
 
-def routing_counts(slot, C: int, slots: int) -> dict:
-    """One MoE layer's counters: top-k choices made (``moe_routed``), those
-    capacity dropped (``moe_dropped``, slot ``>= C``) and rows of expert
-    work issued (``moe_slots``), as int32 scalars."""
-    return {"moe_routed": jnp.int32(slot.size),
-            "moe_dropped": jnp.sum(slot >= C, dtype=jnp.int32),
+def routing_counts(slot, C: int, slots: int, held=None) -> dict:
+    """One MoE layer's counters: top-k choices routed to the experts held
+    here (``moe_routed``; all of them without ``held``), those capacity
+    dropped (``moe_dropped``, slot ``>= C``), rows of expert work issued
+    (``moe_slots``) and, with a ``held`` mask, the choices of experts held
+    elsewhere (``moe_not_held``), as int32 scalars."""
+    if held is None:
+        return {"moe_routed": jnp.int32(slot.size),
+                "moe_dropped": jnp.sum(slot >= C, dtype=jnp.int32),
+                "moe_slots": jnp.int32(slots)}
+    routed = jnp.sum(held, dtype=jnp.int32)
+    return {"moe_routed": routed,
+            "moe_not_held": jnp.int32(slot.size) - routed,
+            "moe_dropped": jnp.sum(held & (slot >= C), dtype=jnp.int32),
             "moe_slots": jnp.int32(slots)}
 
 
@@ -163,6 +250,23 @@ def slot_map(top_i, E: int, C: int) -> SlotMap:
     dest = flat_e * C + jnp.minimum(slot, C - 1)
     return SlotMap(jnp.where(keep, slot, C).reshape(T, k),
                    keep.reshape(T, k), dest.reshape(T, k), src)
+
+
+def held_slot_map(top_i, mc: MoEConfig, C: int):
+    """Capacity slots of the choices of the experts held here
+    (``mc.held_first`` on, ``mc.e_held`` of them); a choice of an expert
+    held elsewhere takes no slot. Returns ``(SlotMap over the held experts,
+    held [T, k])``; without a share, the plain :func:`slot_map` and None."""
+    if not mc.n_held:
+        return slot_map(top_i, mc.e_total, C), None
+    n = mc.e_held
+    held = (top_i >= mc.held_first) & (top_i < mc.held_first + n)
+    # Choices held elsewhere go to a bucket past the held experts, whose
+    # slots are never issued.
+    sm = slot_map(jnp.where(held, top_i - mc.held_first, n), n + 1, C)
+    keep = sm.keep & held
+    return SlotMap(jnp.where(keep, sm.slot, C), keep,
+                   jnp.where(held, sm.dest, 0), sm.src[:n]), held
 
 
 def make_dispatch(top_p, top_i, E: int, C: int):
@@ -558,13 +662,12 @@ def moe_grouped(params, x, mc: MoEConfig, act: str = "swiglu",
     B, S, d = x.shape
     T = B * S
     xt = x.reshape(T, d)
-    E = mc.e_total
     C = cap or capacity(T, mc)
     with jax.named_scope("moe/router"):
-        top_p, top_i = router_topk(params["router"], xt, mc)
+        top_p, top_i, scores = route(params, xt, mc)
 
     with jax.named_scope("moe/dispatch"):
-        sm = slot_map(top_i, E, C)
+        sm, held = held_slot_map(top_i, mc, C)
         top_p = top_p * sm.keep
         disp = dispatch_rows(xt, sm)
 
@@ -576,5 +679,10 @@ def moe_grouped(params, x, mc: MoEConfig, act: str = "swiglu",
 
     with jax.named_scope("moe/combine"):
         y = combine_rows(out_e, top_p, sm)
-    record_moe_counts(routing_counts(sm.slot, C, E * C))
+    counts = routing_counts(sm.slot, C, mc.e_held * C, held)
+    if scores is not None:
+        with jax.named_scope("moe/router"):
+            counts.update(router_stats(*balance_stats(scores, top_i, B), S,
+                                       mc))
+    record_moe_counts(counts)
     return y.astype(x.dtype).reshape(B, S, d)

@@ -4,6 +4,11 @@ Self-contained (no optax in this environment). State is a pytree of the same
 structure as params — m/v in fp32 — so the checkpoint layer and sharding
 rules apply uniformly. ``grad_transform`` hooks (e.g. cross-pod gradient
 compression) run before the moment update.
+
+Leaves named in ``STATE_LEAVES`` are state that the train step moves by its
+own rule (the MoE router's selection bias): they stay float32, and the
+optimizer leaves them, their moments and their masters alone and out of the
+clip's global norm (``launch/steps.py`` moves the bias and its first moment).
 """
 
 from __future__ import annotations
@@ -38,11 +43,22 @@ def schedule(oc: OptConfig, step):
     return oc.lr * jnp.where(step < oc.warmup_steps, warm, cos)
 
 
+STATE_LEAVES = ("router_bias",)
+
+
+def trained(path) -> bool:
+    """Whether the optimizer updates the leaf at ``path``."""
+    return getattr(path[-1], "key", None) not in STATE_LEAVES if path \
+        else True
+
+
 def cast_params(params, dtype=jnp.bfloat16):
-    """Compute-precision copy of the parameter tree (float leaves only)."""
-    return jax.tree.map(
-        lambda p: p.astype(dtype) if jnp.issubdtype(p.dtype, jnp.floating)
-        else p, params)
+    """Compute-precision copy of the parameter tree (float leaves only;
+    ``STATE_LEAVES`` stay as they are)."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, p: p.astype(dtype)
+        if jnp.issubdtype(p.dtype, jnp.floating) and trained(path) else p,
+        params)
 
 
 def init_opt_state(params):
@@ -64,9 +80,13 @@ def global_norm(tree):
 
 
 def clip_by_global_norm(grads, max_norm):
-    norm = global_norm(grads)
+    """Clip over the trained leaves; ``STATE_LEAVES`` pass unchanged."""
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    norm = global_norm([g for path, g in flat if trained(path)])
     scale = jnp.minimum(1.0, max_norm / jnp.maximum(norm, 1e-9))
-    return jax.tree.map(lambda g: g * scale.astype(g.dtype), grads), norm
+    return jax.tree_util.tree_map_with_path(
+        lambda path, g: g * scale.astype(g.dtype) if trained(path) else g,
+        grads), norm
 
 
 @jax.named_scope("optimizer")
@@ -94,8 +114,14 @@ def apply_updates(params, grads, state, oc: OptConfig,
         g = g.astype(jnp.float32)
         return b2 * v + (1 - b2) * g * g
 
-    new_m = jax.tree.map(new_m_fn, grads, state["m"])
-    new_v = jax.tree.map(new_v_fn, grads, state["v"])
+    def only_trained(fn):
+        """``fn(g, state)`` on trained leaves; the state unchanged else."""
+        return lambda path, g, st: fn(g, st) if trained(path) else st
+
+    new_m = jax.tree_util.tree_map_with_path(only_trained(new_m_fn), grads,
+                                             state["m"])
+    new_v = jax.tree_util.tree_map_with_path(only_trained(new_v_fn), grads,
+                                             state["v"])
 
     def upd(master, m, v):
         delta = (m / bc1) / (jnp.sqrt(v / bc2) + oc.eps)
@@ -103,7 +129,9 @@ def apply_updates(params, grads, state, oc: OptConfig,
             delta = delta + oc.weight_decay * master
         return master - lr * delta
 
-    new_master = jax.tree.map(upd, state["master"], new_m, new_v)
+    new_master = jax.tree_util.tree_map_with_path(
+        lambda path, mst, m, v: upd(mst, m, v) if trained(path) else mst,
+        state["master"], new_m, new_v)
     new_params = jax.tree.map(
         lambda p, mst: mst.astype(p.dtype), params, new_master)
     return new_params, {"m": new_m, "v": new_v, "master": new_master,

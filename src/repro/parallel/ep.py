@@ -37,8 +37,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.models.moe import (MoEConfig, combine_rows, dispatch_rows,
-                              router_topk, routing_counts, slot_map)
+from repro.models.moe import (MoEConfig, balance_stats, combine_rows,
+                              dispatch_rows, held_slot_map, route,
+                              router_stats, routing_counts)
 from repro.models.layers import glu_act
 from repro.parallel.ctx import record_moe_counts
 
@@ -151,20 +152,23 @@ def _expert_ffn_local(w_in, w_down, x, act, use_pallas):
     return jnp.einsum("ecf,efd->ecd", h, w_down.astype(x.dtype))
 
 
-def _dispatch_buffers(x2d, router, mc: MoEConfig, ep: int, C: int):
+def _dispatch_buffers(x2d, router_p, mc: MoEConfig, ep: int, C: int):
     """Local routing + gather into the per-(dst, expert) send buffer.
 
-    Returns (send [ep, e_loc, C, d], top_p, sm): ``sm`` is the choices'
-    :class:`SlotMap` over the global experts' (dst, expert) buckets.
+    Returns (send [ep, e_loc, C, d], top_p, top_i, scores, sm, held):
+    ``sm`` is the choices' :class:`SlotMap` over the held experts' (dst,
+    expert) buckets, ``held`` which choices those are (None where every
+    expert is held), ``scores`` as :func:`repro.models.moe.route` gives them.
     """
     d = x2d.shape[1]
     with jax.named_scope("moe/router"):
-        top_p, top_i = router_topk(router, x2d, mc)
+        top_p, top_i, scores = route(router_p, x2d, mc)
     with jax.named_scope("moe/dispatch"):
-        sm = slot_map(top_i, mc.e_total, C)
+        sm, held = held_slot_map(top_i, mc, C)
         top_p = top_p * sm.keep
         send = dispatch_rows(x2d, sm)
-    return send.reshape(ep, mc.e_total // ep, C, d), top_p, sm
+    return (send.reshape(ep, mc.e_held // ep, C, d), top_p, top_i, scores,
+            sm, held)
 
 
 def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
@@ -220,7 +224,7 @@ def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
 
     def moe_impl(params, x, mc: MoEConfig):
         B, S, d = x.shape
-        e_loc = mc.e_total // ep
+        e_loc = mc.e_held // ep
 
         if epc.dp_batch and B % (ep * max(1, np.prod(
                 [mesh.shape[a] for a in dp]))) == 0:
@@ -230,19 +234,28 @@ def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
                        epc.axis if S % ep == 0 and S > 1 else None, None)
 
         # Each device's counters leave the shard as its own entry of a
-        # vector over every mesh axis.
+        # vector over every mesh axis; a sigmoid router's per-sequence
+        # balance statistics leave summed over the shards of a sequence.
         per_device = P(tuple(mesh.axis_names))
+        router_p = {"router": params["router"]}
+        router_specs = {"router": P(None, None)}
+        out_specs = (x_spec, per_device)
+        if mc.score == "sigmoid":
+            router_p["router_bias"] = params["router_bias"]
+            router_specs["router_bias"] = P(None)
+            out_specs += ((P(x_spec[0], None),) * 2,)
 
         @partial(jax.shard_map, mesh=mesh,
-                 in_specs=(P(None, None), P(epc.axis, None, None),
+                 in_specs=(router_specs, P(epc.axis, None, None),
                            P(epc.axis, None, None), x_spec),
-                 out_specs=(x_spec, per_device), check_vma=False)
-        def run(router, w_in, w_down, x_loc):
+                 out_specs=out_specs, check_vma=False)
+        def run(router_p, w_in, w_down, x_loc):
             b, s, _ = x_loc.shape
             T = b * s
             x2d = x_loc.reshape(T, d)
             C = _pair_capacity(T, mc, ep, epc.capacity_factor)
-            send, top_p, sm = _dispatch_buffers(x2d, router, mc, ep, C)
+            send, top_p, top_i, scores, sm, held = _dispatch_buffers(
+                x2d, router_p, mc, ep, C)
 
             if epc.mode == "baseline":
                 with jax.named_scope("moe/exchange"):
@@ -263,13 +276,23 @@ def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
 
             with jax.named_scope("moe/combine"):
                 # back: results at their send slots, expert-major like send.
-                y = combine_rows(back.reshape(mc.e_total, C, d), top_p, sm)
+                y = combine_rows(back.reshape(mc.e_held, C, d), top_p, sm)
             counts = {k: v[None] for k, v in
-                      routing_counts(sm.slot, C, rows).items()}
-            return y.astype(x_loc.dtype).reshape(b, s, d), counts
+                      routing_counts(sm.slot, C, rows, held).items()}
+            out = (y.astype(x_loc.dtype).reshape(b, s, d), counts)
+            if scores is None:
+                return out
+            with jax.named_scope("moe/router"):
+                stats = balance_stats(scores, top_i, b)
+                if x_spec[1] is not None:       # sequences split over chips
+                    stats = jax.lax.psum(stats, epc.axis)
+            return out + (stats,)
 
-        y, counts = run(params["router"], params["w_in"],
-                        params["w_down"], x)
+        y, counts, *stats = run(router_p, params["w_in"],
+                                params["w_down"], x)
+        if stats:
+            with jax.named_scope("moe/router"):
+                counts = dict(counts, **router_stats(*stats[0], S, mc))
         record_moe_counts(counts)
         return y
 

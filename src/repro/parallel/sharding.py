@@ -73,7 +73,7 @@ class ShardingRules:
     def param_spec(self, path: tuple[str, ...], shape: tuple[int, ...]) -> P:
         keys = [getattr(k, "key", getattr(k, "idx", k)) for k in path]
         name = keys[-1] if keys else ""
-        stacked = any(k in ("blocks", "super") for k in keys)
+        stacked = any(k in ("blocks", "super", "dense_blocks") for k in keys)
         lead = (None,) if stacked else ()
         if self.mode in ("zero1", "ep_dp"):
             body = self._param_spec_dp(name, shape[len(lead):])

@@ -63,6 +63,20 @@ def test_one_chip_phases_on_cpu(smoke, capsys):
     assert not os.path.exists(smoke.CKPT_DIR)
 
 
+def test_moonlight_phase_on_cpu(smoke, capsys, monkeypatch):
+    """Moonlight's step at smoke widths, a quarter share of its experts and
+    vocabulary, through the trainer's entry point, then the JSON last
+    line."""
+    monkeypatch.setattr(smoke, "MOONLIGHT_ARGS", [
+        "--arch", "moonlight-16b-a3b", "--smoke", "--seq", "32",
+        "--expert-share", "4"])
+    assert smoke.main(["--model", "moonlight"]) == 0
+    out = capsys.readouterr().out.strip().splitlines()
+    assert json.loads(out[-1])["ok"] is True
+    assert any(line.startswith("step    1 loss ") for line in out)
+    assert not os.path.exists(smoke.CKPT_DIR)
+
+
 _FOUR = """
 import json, sys, jax
 sys.path.insert(0, sys.argv[1])
