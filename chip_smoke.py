@@ -7,9 +7,10 @@
 
 One chip: trains granite-moe-3b-a800m at its published widths, its depth
 cut to 4 layers, for 5 steps through ``repro.launch.train.main`` on a 1x1
-mesh (the fixed-capacity EP path); then runs one full-width MoE layer
-through the EP path twice, with einsum experts and with the Pallas kernels,
-and compares both with a float32 reference.
+mesh (the one-chip EP path: kept rows only, grouped GEMM); then runs one
+full-width MoE layer through the EP path twice, with the grouped GEMM over
+the kept rows and with the fixed-shape Pallas kernels over the capacity
+buffer, and compares both with a float32 reference.
 
 Moonlight: one training step of Moonlight-16B-A3B (MLA, shared experts,
 sigmoid routing with its bias, a leading dense layer) at its published
@@ -109,8 +110,8 @@ def print_peak_memory(devs) -> None:
 
 def moe_layer_check(cfg, T: int) -> None:
     """One MoE layer of ``cfg`` on T tokens through ``make_moe_ep`` on a 1x1
-    mesh, with einsum and with Pallas experts, vs ``moe_dense_ref`` in
-    float32."""
+    mesh, with the grouped GEMM over the kept rows and with the fixed-shape
+    Pallas kernels, vs ``moe_dense_ref`` in float32."""
     import jax
     import jax.numpy as jnp
 
@@ -136,7 +137,7 @@ def moe_layer_check(cfg, T: int) -> None:
         with jax.set_mesh(mesh), jax.default_matmul_precision("highest"):
             y = jax.jit(lambda p, x: impl(p, x, mc))(params, x)
         err = float(jnp.max(jnp.abs(y.astype(jnp.float32) - ref)))
-        name = "pallas" if use_pallas else "einsum"
+        name = "pallas" if use_pallas else "ragged"
         print(f"moe_layer {name}: max_abs_err {err:.6g} max_abs_ref "
               f"{scale:.6g} tol {MOE_TOL * scale:.6g} "
               f"(T {T}, d_model {cfg.d_model}, experts {mc.e_total}, "

@@ -35,9 +35,9 @@ Production loop responsibilities, all testable on CPU:
   ``train_loop/ckpt`` (save and clean-up). The profiler records them, when
   it runs, on the clock of the device trace; otherwise each costs about
   a microsecond of host time. The MoE counters a step's metrics carry
-  (``moe_routed``, ``moe_dropped``, ``moe_slots``) are fetched with the
-  loss, in one transfer, into each ``metrics_log`` entry; no step waits on
-  more than the loss.
+  (``moe_routed``, ``moe_dropped``, ``moe_slots``, ``moe_rows_issued``)
+  are fetched with the loss, in one transfer, into each ``metrics_log``
+  entry; no step waits on more than the loss.
 """
 
 from __future__ import annotations
@@ -54,7 +54,8 @@ from repro.checkpoint import ckpt as CK
 span = jax.profiler.StepTraceAnnotation
 phase = jax.profiler.TraceAnnotation
 # The train step's MoE counters (``launch/steps.py``), logged where present.
-MOE_COUNTERS = ("moe_routed", "moe_dropped", "moe_slots")
+MOE_COUNTERS = ("moe_routed", "moe_dropped", "moe_slots",
+                "moe_rows_issued")
 LOGGED = ("loss", "grad_norm") + MOE_COUNTERS
 
 

@@ -6,13 +6,14 @@ constraint are installed via the ambient contexts at *trace* time, keeping
 the model code mesh-agnostic (the paper's low-intrusion integration).
 
 The train step's metrics are ``loss``, ``grad_norm``, ``lr`` and, for a
-fixed-capacity MoE model, the int32 counters ``moe_routed``, ``moe_dropped``
-and ``moe_slots`` (``models.moe.routing_counts``; ``moe_not_held`` too where
-a layer holds a share of the experts) summed over layers and chips. With a
-sigmoid router the step also reports ``moe_balance_loss`` (part of
-``loss``) and moves each MoE layer's router bias by its load
-(``models.moe.bias_step``) after the optimizer, which leaves the bias alone;
-the bias's slot of Adam's first moment keeps its running excess load.
+fixed-capacity MoE model, the int32 counters ``moe_routed``, ``moe_dropped``,
+``moe_slots`` and ``moe_rows_issued`` (``models.moe.routing_counts``;
+``moe_not_held`` too where a layer holds a share of the experts) summed over
+layers and chips. With a sigmoid router the step also reports
+``moe_balance_loss`` (part of ``loss``) and moves each MoE layer's router
+bias by its load (``models.moe.bias_step``) after the optimizer, which
+leaves the bias alone; the bias's slot of Adam's first moment keeps its
+running excess load.
 """
 
 from __future__ import annotations
@@ -132,9 +133,9 @@ def make_steps(cfg, mesh, *, opt: Optional[adamw.OptConfig] = None,
             for tree in (params2, opt_state2["master"]):
                 tree["blocks"]["moe"]["router_bias"] = bias
         metrics["loss"] = lv
-        # The MoE layers' counters (moe_routed, moe_dropped, moe_slots),
-        # summed over layers and chips; absent where the MoE path records
-        # none (the dropless path).
+        # The MoE layers' counters (moe_routed, moe_dropped, moe_slots,
+        # moe_rows_issued), summed over layers and chips; absent where the
+        # MoE path records none (the dropless path).
         metrics.update(counts)
         # Surface per-step SSC cache deltas (recompiles this step, hit
         # count, occupancy). Host-side counters only exist eagerly; under

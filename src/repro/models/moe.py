@@ -12,6 +12,9 @@ Three execution paths, all numerically equivalent (tests assert it):
 * EP-sharded execution lives in ``repro/parallel/ep.py`` (shard_map): the
   ``baseline`` mode uses a collective AllToAll, the ``hyperparallel`` mode
   the RATR chunked-ppermute schedule mirroring the paper's one-sided tasks.
+  On one chip it packs the kept rows end to end instead
+  (``compact_slot_map``) and runs them through a grouped GEMM
+  (``grouped_expert_ffn``), with the same drops.
 
 ``plan_from_routing`` bridges this layer to the scheduling stack: it turns a
 batch's actual (imbalanced) top-k assignment into a compilable
@@ -33,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.ragged import grouped_matmul
 from repro.parallel.ctx import record_moe_counts
 
 from .layers import glu_act
@@ -187,21 +191,25 @@ def capacity(tokens: int, mc: MoEConfig, ep: int = 1) -> int:
     return max(ep, ((c + ep - 1) // ep) * ep)
 
 
-def routing_counts(slot, C: int, slots: int, held=None) -> dict:
+def routing_counts(slot, C: int, slots: int, issued, held=None) -> dict:
     """One MoE layer's counters: top-k choices routed to the experts held
     here (``moe_routed``; all of them without ``held``), those capacity
-    dropped (``moe_dropped``, slot ``>= C``), rows of expert work issued
-    (``moe_slots``) and, with a ``held`` mask, the choices of experts held
-    elsewhere (``moe_not_held``), as int32 scalars."""
+    dropped (``moe_dropped``, slot ``>= C``), the layer's capacity rows
+    (``moe_slots``, ``slots``), the rows its expert GEMM computes
+    (``moe_rows_issued``, ``issued``: the capacity rows the fixed-shape
+    paths multiply, or the kept rows rounded out to the ragged kernel's row
+    tiles) and, with a ``held`` mask, the choices of experts held elsewhere
+    (``moe_not_held``), as int32 scalars."""
+    counts = {"moe_slots": jnp.int32(slots),
+              "moe_rows_issued": jnp.asarray(issued, jnp.int32)}
     if held is None:
         return {"moe_routed": jnp.int32(slot.size),
-                "moe_dropped": jnp.sum(slot >= C, dtype=jnp.int32),
-                "moe_slots": jnp.int32(slots)}
+                "moe_dropped": jnp.sum(slot >= C, dtype=jnp.int32), **counts}
     routed = jnp.sum(held, dtype=jnp.int32)
     return {"moe_routed": routed,
             "moe_not_held": jnp.int32(slot.size) - routed,
             "moe_dropped": jnp.sum(held & (slot >= C), dtype=jnp.int32),
-            "moe_slots": jnp.int32(slots)}
+            **counts}
 
 
 def expert_ffn(w_in, w_down, x, act: str = "swiglu"):
@@ -209,6 +217,15 @@ def expert_ffn(w_in, w_down, x, act: str = "swiglu"):
     h = jnp.einsum("ecd,edf->ecf", x, w_in.astype(x.dtype))
     h = glu_act(h, act)
     return jnp.einsum("ecf,efd->ecd", h, w_down.astype(x.dtype))
+
+
+def grouped_expert_ffn(w_in, w_down, x, group_sizes, act: str = "swiglu"):
+    """x: [R, d] rows sorted by expert, ``group_sizes`` [E] of them from
+    row 0 on → [R, d] (``kernels.ragged``); rows past the groups are left
+    undefined."""
+    h = grouped_matmul(x, w_in.astype(x.dtype), group_sizes)
+    return grouped_matmul(glu_act(h, act), w_down.astype(x.dtype),
+                          group_sizes)
 
 
 class SlotMap(NamedTuple):
@@ -269,6 +286,32 @@ def held_slot_map(top_i, mc: MoEConfig, C: int):
                    jnp.where(held, sm.dest, 0), sm.src[:n]), held
 
 
+def compact_slot_map(sm: SlotMap, rows: int):
+    """The kept choices of ``sm`` packed end to end, expert after expert,
+    into a buffer of ``rows`` rows: expert e's choice at slot s sits at row
+    ``koff[e] + s``, ``koff`` the running sum of ``kept = min(count, C)``
+    over the experts before e. The drops are ``sm``'s.
+
+    Returns ``(SlotMap, kept [E] int32)``: the map's ``dest`` [T, k] is a
+    row of the buffer (0 where not kept) and its ``src`` [rows] the flat
+    choice filling each row, ``T * k`` past the last kept row; ``slot`` and
+    ``keep`` are ``sm``'s."""
+    E, C = sm.src.shape
+    n = sm.keep.size
+    kept = jnp.sum(sm.src < n, axis=1, dtype=jnp.int32)
+    ends = jnp.cumsum(kept)
+    koff = ends - kept
+    dest = jnp.where(sm.keep, koff[sm.dest // C] + sm.dest % C, 0)
+    # Row r belongs to the first expert whose rows end past it; its choice
+    # is that expert's slot r - koff[e] of the capacity map: no scatter.
+    r = jnp.arange(rows, dtype=jnp.int32)
+    e = jnp.minimum(jnp.searchsorted(ends, r, side="right",
+                                     method="compare_all"), E - 1)
+    flat = jnp.clip(e * C + r - koff[e], 0, E * C - 1)
+    src = jnp.where(r < ends[-1], sm.src.reshape(-1)[flat], n)
+    return SlotMap(sm.slot, sm.keep, dest, src), kept
+
+
 def make_dispatch(top_p, top_i, E: int, C: int):
     """Position-in-expert assignment under fixed capacity.
 
@@ -279,9 +322,12 @@ def make_dispatch(top_p, top_i, E: int, C: int):
     return top_p * sm.keep, top_i, sm.slot
 
 
-# Rows move between tokens and the capacity buffer only by gathers, in both
-# directions: the slots form a permutation of the kept choices, so the
-# transpose of each gather is a gather through the inverse map.
+# Rows move between tokens and the expert buffer (the capacity buffer
+# [E, C, d], or the compact one [R, d] of ``compact_slot_map``) only by
+# gathers, in both directions: the slots form a permutation of the kept
+# choices, so the transpose of each gather is a gather through the inverse
+# map. Buffer rows no choice fills are selected away, never weighted by
+# zero, so whatever they hold never reaches a token.
 
 def _token_rows(x2d, src, k: int):
     """x2d [T, d] rows of the tokens whose choices fill ``src``'s slots;
@@ -293,7 +339,7 @@ def _token_rows(x2d, src, k: int):
 
 
 def _slot_rows(buf, dest, keep):
-    """buf [E, C, d] rows at the choices' slots [T, k, d]; zero where
+    """buf [..., d] rows at the choices' flat slots [T, k, d]; zero where
     dropped."""
     rows = buf.reshape(-1, buf.shape[-1])[dest]
     return jnp.where(keep[..., None], rows, jnp.zeros((), buf.dtype))
@@ -301,8 +347,8 @@ def _slot_rows(buf, dest, keep):
 
 @jax.custom_vjp
 def dispatch_rows(x2d, sm: SlotMap):
-    """Tokens [T, d] → capacity buffer [E, C, d]: slot (e, c) holds the
-    token of choice ``sm.src[e, c]``, zeros where empty."""
+    """Tokens [T, d] → buffer ``sm.src.shape + (d,)``: each slot holds the
+    token of its choice ``sm.src``, zeros where empty."""
     return _token_rows(x2d, sm.src, sm.dest.shape[1])
 
 
@@ -325,7 +371,7 @@ dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 @jax.custom_vjp
 def combine_rows(buf, top_p, sm: SlotMap):
-    """Expert outputs [E, C, d] → tokens [T, d]:
+    """Expert outputs ``sm.src.shape + (d,)`` → tokens [T, d]:
     ``y[t] = Σ_j top_p[t, j] · buf[sm.dest[t, j]]`` over the kept choices."""
     return _combine_fwd(buf, top_p, sm)[0]
 
@@ -679,7 +725,7 @@ def moe_grouped(params, x, mc: MoEConfig, act: str = "swiglu",
 
     with jax.named_scope("moe/combine"):
         y = combine_rows(out_e, top_p, sm)
-    counts = routing_counts(sm.slot, C, mc.e_held * C, held)
+    counts = routing_counts(sm.slot, C, mc.e_held * C, mc.e_held * C, held)
     if scores is not None:
         with jax.named_scope("moe/router"):
             counts.update(router_stats(*balance_stats(scores, top_i, B), S,

@@ -17,14 +17,18 @@ assert it on a multi-device CPU mesh):
 Routing uses per-(destination, expert) fixed capacity so all comm shapes are
 static. Every device routes its local tokens with the replicated router;
 combine applies top-k weights back at the source — exactly the paper's
-Dispatch→…→Combine boundary.
+Dispatch→…→Combine boundary. An EP group of one chip exchanges nothing: it
+packs the kept rows end to end instead of into the capacity buffer, and a
+grouped GEMM computes only those (``models.moe.compact_slot_map``,
+``kernels.ragged``), with the same drops.
 
 Every stage runs under a ``jax.named_scope`` (``moe/router``,
 ``moe/dispatch``, ``moe/exchange``, ``moe/expert_ffn``, ``moe/combine``), so
 each device op, forward, transposed or recomputed, names its stage in its
 HLO ``op_name``. Each call hands ``ctx.record_moe_counts`` the layer's
 counters (``models.moe.routing_counts``) as per-device vectors: each chip
-counts its own top-k choices, capacity drops and expert rows issued.
+counts its own top-k choices, capacity drops, capacity rows and the rows
+its expert GEMMs compute.
 """
 
 from __future__ import annotations
@@ -37,8 +41,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.ragged import buffer_rows, rows_issued
 from repro.models.moe import (MoEConfig, balance_stats, combine_rows,
-                              dispatch_rows, held_slot_map, route,
+                              compact_slot_map, dispatch_rows,
+                              grouped_expert_ffn, held_slot_map, route,
                               router_stats, routing_counts)
 from repro.models.layers import glu_act
 from repro.parallel.ctx import record_moe_counts
@@ -63,11 +69,11 @@ def _pair_capacity(t_loc: int, mc: MoEConfig, ep: int,
 
 
 def plan_from_dispatch(top_i, mc: MoEConfig, ep: int, C: int):
-    """RoutingPlan for the rows ``_dispatch_buffers`` actually materialises.
+    """RoutingPlan for the rows the EP path's capacity buffers materialise.
 
     ``top_i``: per-source-rank expert choices [ep, T_loc, k]. Capacity here
     is *per (source device, global expert)* — the slot semantics of
-    ``_dispatch_buffers`` — so ``counts[s, d, e] = min(#choices, C)``. The
+    ``_route_local`` — so ``counts[s, d, e] = min(#choices, C)``. The
     returned plan describes the useful (non-padding) rows of the EP path's
     fixed-capacity send buffers, letting the same batch be compiled by the
     scheduling stack and profiled for skew.
@@ -152,23 +158,37 @@ def _expert_ffn_local(w_in, w_down, x, act, use_pallas):
     return jnp.einsum("ecf,efd->ecd", h, w_down.astype(x.dtype))
 
 
-def _dispatch_buffers(x2d, router_p, mc: MoEConfig, ep: int, C: int):
-    """Local routing + gather into the per-(dst, expert) send buffer.
+def _route_local(x2d, router_p, mc: MoEConfig, C: int):
+    """Local routing and the choices' capacity slots.
 
-    Returns (send [ep, e_loc, C, d], top_p, top_i, scores, sm, held):
-    ``sm`` is the choices' :class:`SlotMap` over the held experts' (dst,
+    Returns (top_p, top_i, scores, sm, held): ``top_p`` zero where dropped,
+    ``sm`` the choices' :class:`SlotMap` over the held experts' (dst,
     expert) buckets, ``held`` which choices those are (None where every
     expert is held), ``scores`` as :func:`repro.models.moe.route` gives them.
     """
-    d = x2d.shape[1]
     with jax.named_scope("moe/router"):
         top_p, top_i, scores = route(router_p, x2d, mc)
     with jax.named_scope("moe/dispatch"):
         sm, held = held_slot_map(top_i, mc, C)
         top_p = top_p * sm.keep
-        send = dispatch_rows(x2d, sm)
-    return (send.reshape(ep, mc.e_held // ep, C, d), top_p, top_i, scores,
-            sm, held)
+    return top_p, top_i, scores, sm, held
+
+
+def _compact_moe(x2d, top_p, sm, w_in, w_down, act):
+    """One chip's expert layer without the capacity buffer: the kept rows
+    packed end to end (``compact_slot_map``, the same drops), a grouped
+    GEMM over them, and the weighted combine. Returns (y [T, d], the rows
+    the GEMM computes)."""
+    T, k = sm.keep.shape
+    R = buffer_rows(min(T * k, sm.src.size))
+    with jax.named_scope("moe/dispatch"):
+        csm, kept = compact_slot_map(sm, R)
+        issued = rows_issued(kept, R)
+        xin = dispatch_rows(x2d, csm)
+    with jax.named_scope("moe/expert_ffn"):
+        out = grouped_expert_ffn(w_in, w_down, xin, kept, act)
+    with jax.named_scope("moe/combine"):
+        return combine_rows(out, top_p, csm), issued
 
 
 def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
@@ -204,6 +224,10 @@ def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
     """
     ep = mesh.shape[epc.axis]
     dp = tuple(a for a in mesh.axis_names if a != epc.axis)
+    # One chip exchanges nothing, so nothing needs the fixed-size chunks:
+    # its experts take only the kept rows, packed end to end (the
+    # fixed-shape Pallas kernels keep the capacity buffer).
+    compact = ep == 1 and not epc.use_pallas
     if (bucket is not None or inter_bucket is not None) and plan is None:
         raise ValueError(
             "make_moe_ep(bucket=.../inter_bucket=...) quantizes a routing "
@@ -254,9 +278,29 @@ def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
             T = b * s
             x2d = x_loc.reshape(T, d)
             C = _pair_capacity(T, mc, ep, epc.capacity_factor)
-            send, top_p, top_i, scores, sm, held = _dispatch_buffers(
-                x2d, router_p, mc, ep, C)
+            top_p, top_i, scores, sm, held = _route_local(
+                x2d, router_p, mc, C)
+            if compact:
+                y, rows = _compact_moe(x2d, top_p, sm, w_in, w_down, act)
+            else:
+                y, rows = _capacity_moe(x2d, top_p, sm, w_in, w_down, C)
+            counts = {k: v[None] for k, v in routing_counts(
+                sm.slot, C, mc.e_held * C, rows, held).items()}
+            out = (y.astype(x_loc.dtype).reshape(b, s, d), counts)
+            if scores is None:
+                return out
+            with jax.named_scope("moe/router"):
+                stats = balance_stats(scores, top_i, b)
+                if x_spec[1] is not None:       # sequences split over chips
+                    stats = jax.lax.psum(stats, epc.axis)
+            return out + (stats,)
 
+        def _capacity_moe(x2d, top_p, sm, w_in, w_down, C):
+            """The exchange over the EP group in fixed [e_loc, C, d]
+            chunks. Returns (y [T, d], the rows the expert GEMMs
+            compute)."""
+            with jax.named_scope("moe/dispatch"):
+                send = dispatch_rows(x2d, sm).reshape(ep, e_loc, C, d)
             if epc.mode == "baseline":
                 with jax.named_scope("moe/exchange"):
                     recv = jax.lax.all_to_all(send, epc.axis, split_axis=0,
@@ -276,17 +320,8 @@ def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
 
             with jax.named_scope("moe/combine"):
                 # back: results at their send slots, expert-major like send.
-                y = combine_rows(back.reshape(mc.e_held, C, d), top_p, sm)
-            counts = {k: v[None] for k, v in
-                      routing_counts(sm.slot, C, rows, held).items()}
-            out = (y.astype(x_loc.dtype).reshape(b, s, d), counts)
-            if scores is None:
-                return out
-            with jax.named_scope("moe/router"):
-                stats = balance_stats(scores, top_i, b)
-                if x_spec[1] is not None:       # sequences split over chips
-                    stats = jax.lax.psum(stats, epc.axis)
-            return out + (stats,)
+                return (combine_rows(back.reshape(mc.e_held, C, d), top_p,
+                                     sm), rows)
 
         y, counts, *stats = run(router_p, params["w_in"],
                                 params["w_down"], x)
@@ -307,8 +342,9 @@ def make_moe_ep(mesh, epc: EPConfig, act: str = "swiglu", plan=None,
         always occupy the head of each slot, so the sliced rows are exactly
         the routed ones — and all-padding steps (cap 0) are skipped.
 
-        Returns ``(back, rows)``: the results, and the expert rows this
-        device's FFN was issued over all steps.
+        Returns ``(back, rows)``: the results, and the rows this device's
+        expert GEMMs compute over all steps (``moe_rows_issued``; below the
+        capacity rows ``moe_slots`` only where ``ring_caps`` slice chunks).
         """
         r = jax.lax.axis_index(epc.axis)
         e_loc, C, d = send.shape[1], send.shape[2], send.shape[3]

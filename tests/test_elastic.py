@@ -39,14 +39,18 @@ from repro.ft.runner import ElasticContext, FTConfig, RunState, train_loop
 # ---------------------------------------------------------------------------
 # remap_plan properties over the plan-generator zoo × random dead sets.
 # e_total = 4 * 3 = 12 divides every survivor count 1..4, so any dead set
-# is legal.
+# is legal. A hotspot plan of 4 rows a cell holds backgrounds 0 and 1 but
+# not 2 (``hotspot_plan`` refuses it); that one takes 5 rows.
 # ---------------------------------------------------------------------------
 
 def _make_plan(kind: str, seed: int) -> RoutingPlan:
     if kind == "skewed":
         return skewed_plan(4, 3, 8 + seed % 5, alpha=0.5 + (seed % 4) * 0.5)
     if kind == "hotspot":
-        return hotspot_plan(4, 3, 4 + seed % 4, background=seed % 3)
+        rows, background = 4 + seed % 4, seed % 3
+        if background == 2:
+            rows = max(rows, 5)
+        return hotspot_plan(4, 3, rows, background=background)
     if kind == "node_limited":
         return node_limited_plan(4, 3, 4 + seed % 4, node_size=2,
                                  m_nodes=1 + seed % 2)

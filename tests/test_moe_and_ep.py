@@ -213,6 +213,234 @@ def test_moe_gradient_has_no_row_scatter(path):
                        for s in ("moe/dispatch", "moe/combine")), line
 
 
+def _counted(impl):
+    """jit of ``impl(params, x)`` returning (y, the layer's counters
+    summed over devices, router load left out)."""
+    def run(p, x):
+        with moe_counts_scope() as got:
+            y = impl(p, x)
+        (counts,) = got
+        return y, {k: jnp.sum(v) for k, v in counts.items()
+                   if k != "moe_load"}
+    return jax.jit(run)
+
+
+def _one_chip_ep(mc, capacity_factor, **kw):
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    impl = make_moe_ep(mesh, EPConfig("hyperparallel",
+                                      capacity_factor=capacity_factor, **kw))
+    return lambda p, x: impl(p, x, mc)
+
+
+COMPACT_CASES = {
+    "softmax": MoEConfig(n_experts=8, top_k=2, d_expert=16),
+    "softmax_held": MoEConfig(n_experts=8, top_k=2, d_expert=16, n_held=3,
+                              held_first=2),
+    "sigmoid": MoEConfig(n_experts=8, top_k=2, d_expert=16, score="sigmoid",
+                         routed_scale=2.5),
+    "sigmoid_held": MoEConfig(n_experts=8, top_k=2, d_expert=16,
+                              score="sigmoid", routed_scale=2.5, n_held=3,
+                              held_first=1),
+}
+
+
+def _interpret_tpu_kernel(monkeypatch):
+    """Have ``kernels.ragged`` take the TPU's kernel (megablox) and count
+    its row tiles, with the kernel run by Pallas's interpreter, so the
+    TPU's path runs on the CPU."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops
+    from repro.kernels import ragged
+
+    gmm = ops.gmm
+    monkeypatch.setattr(
+        ops, "gmm", lambda *a, **kw: gmm(*a, **kw, interpret=True))
+    monkeypatch.setattr(ragged, "on_tpu", lambda: True)
+
+
+@pytest.mark.parametrize("kernel", ["ragged_dot", "megablox"])
+@pytest.mark.parametrize("case", sorted(COMPACT_CASES))
+@pytest.mark.parametrize("capacity_factor", [1.0, 4.0],
+                         ids=["drops", "drops_none"])
+def test_compact_path_matches_capacity_path(case, capacity_factor, kernel,
+                                            monkeypatch):
+    """On one chip the EP path packs the kept rows end to end and runs a
+    grouped GEMM over them, by either kernel; it gives the capacity
+    buffer's output, gradients and counters (``moe_grouped`` at the same
+    capacity), with and without drops, a held share and the sigmoid
+    router."""
+    if kernel == "megablox":
+        _interpret_tpu_kernel(monkeypatch)
+    mc = COMPACT_CASES[case]
+    params = init_moe(KEY, 32, mc)
+    if mc.score == "sigmoid":
+        params["router_bias"] = jax.random.normal(KEY, (8,)) * 0.1
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 64, 32), jnp.float32)
+    C = _pair_capacity(128, mc, 1, capacity_factor)
+    compact = _one_chip_ep(mc, capacity_factor)
+
+    def capacity_path(p, x):
+        return moe_grouped(p, x, mc, cap=C)
+
+    y, counts = _counted(compact)(params, x)
+    want_y, want_counts = _counted(capacity_path)(params, x)
+    assert (int(counts["moe_dropped"]) > 0) == (capacity_factor == 1.0)
+    for k in ("moe_routed", "moe_dropped", "moe_slots", "moe_not_held"):
+        if k in want_counts:
+            assert int(counts[k]) == int(want_counts[k]), k
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y),
+                               rtol=1e-5, atol=1e-5)
+
+    def grads(f):
+        return jax.jit(jax.grad(lambda p, x: jnp.sum(jnp.sin(f(p, x))),
+                                argnums=(0, 1)))(params, x)
+    g, want_g = grads(compact), grads(capacity_path)
+    for name in ("router", "w_in", "w_down"):
+        np.testing.assert_allclose(np.asarray(g[0][name]),
+                                   np.asarray(want_g[0][name]),
+                                   rtol=1e-5, atol=1e-5, err_msg=name)
+    np.testing.assert_allclose(np.asarray(g[1]), np.asarray(want_g[1]),
+                               rtol=1e-5, atol=1e-5, err_msg="x")
+
+
+def _poison_tail(matmul):
+    """``matmul`` with NaN in the rows past the last group of its output
+    and of its gradient by ``lhs``: rows a kernel may leave undefined."""
+    def nan_tail(a, group_sizes):
+        tail = jnp.arange(a.shape[0]) >= jnp.sum(group_sizes)
+        return jnp.where(tail[:, None], jnp.nan, a)
+
+    @jax.custom_vjp
+    def poisoned(lhs, rhs, group_sizes):
+        return nan_tail(matmul(lhs, rhs, group_sizes), group_sizes)
+
+    def fwd(lhs, rhs, group_sizes):
+        out, vjp = jax.vjp(lambda a, b: matmul(a, b, group_sizes), lhs, rhs)
+        return nan_tail(out, group_sizes), (vjp, group_sizes)
+
+    def bwd(res, g):
+        vjp, group_sizes = res
+        dlhs, drhs = vjp(g)
+        return nan_tail(dlhs, group_sizes), drhs, None
+
+    poisoned.defvjp(fwd, bwd)
+    return poisoned
+
+
+@pytest.mark.parametrize("kernel", ["ragged_dot", "megablox"])
+@pytest.mark.parametrize("capacity_factor", [1.0, 4.0],
+                         ids=["drops", "drops_none"])
+def test_compact_path_selects_away_the_rows_past_the_groups(
+        monkeypatch, capacity_factor, kernel):
+    """The grouped GEMM may leave the rows past the last group undefined,
+    forward and in its gradient by ``lhs``: with a kernel that writes NaN
+    there, the layer's output and its gradients by ``x``, ``w_in``,
+    ``w_down`` and the router stay finite and equal to the clean run's."""
+    from repro.models import moe
+
+    if kernel == "megablox":
+        _interpret_tpu_kernel(monkeypatch)
+    mc = COMPACT_CASES["softmax_held"]
+    params = init_moe(KEY, 32, mc)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 64, 32), jnp.float32)
+    impl = _one_chip_ep(mc, capacity_factor)
+
+    def run():
+        y = jax.jit(impl)(params, x)
+        g = jax.jit(jax.grad(lambda p, x: jnp.sum(jnp.sin(impl(p, x))),
+                             argnums=(0, 1)))(params, x)
+        return y, g
+
+    clean = run()
+    monkeypatch.setattr(moe, "grouped_matmul",
+                        _poison_tail(moe.grouped_matmul))
+    dirty = run()
+    leaves = jax.tree.leaves(dirty)
+    assert all(bool(jnp.all(jnp.isfinite(a))) for a in leaves)
+    for a, b in zip(jax.tree.leaves(clean), leaves):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_tpu_grouped_matmul_matches_ragged_dot(monkeypatch):
+    """The TPU's grouped GEMM (megablox, in interpret mode here) with the
+    tiling ``kernels.ragged`` gives it, against ``jax.lax.ragged_dot``:
+    groups that end short of the buffer, share row tiles and include an
+    empty one; forward and both gradients over the groups' rows, and a
+    zero weight gradient for the empty group."""
+    from repro.kernels import ragged
+
+    _interpret_tpu_kernel(monkeypatch)
+    m, k, n = 1024, 128, 256
+    sizes = jnp.asarray([300, 0, 211, 17, 100], jnp.int32)
+    rows = int(sizes.sum())
+    kx, kw, kg = jax.random.split(KEY, 3)
+    lhs = jax.random.normal(kx, (m, k), jnp.float32)
+    rhs = jax.random.normal(kw, (5, k, n), jnp.float32)
+    dy = jax.random.normal(kg, (m, n), jnp.float32).at[rows:].set(0.0)
+
+    def run(f):
+        y, vjp = jax.vjp(lambda a, b: f(a, b, sizes), lhs, rhs)
+        return (y[:rows],) + vjp(dy)
+
+    y, dlhs, drhs = run(ragged.tpu_grouped_matmul)
+    want_y, want_dlhs, want_drhs = run(
+        lambda a, b, s: jax.lax.ragged_dot(a, b, s))
+    np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(dlhs[:rows], want_dlhs[:rows], rtol=1e-5,
+                               atol=1e-4)
+    np.testing.assert_allclose(drhs, want_drhs, rtol=1e-5, atol=1e-4)
+    assert not np.any(np.asarray(drhs[1]))
+
+
+def _tile_rows(group_sizes: np.ndarray, tm: int) -> int:
+    """Rows of the row tiles each nonempty group touches, by a loop."""
+    rows, start = 0, 0
+    for n in group_sizes:
+        if n:
+            rows += (-(-(start + n) // tm) - start // tm) * tm
+        start += n
+    return rows
+
+
+@pytest.mark.parametrize("path", ["compact", "compact_megablox", "grouped",
+                                  "pallas"])
+def test_rows_issued_counts_what_the_expert_gemm_computes(path,
+                                                          monkeypatch):
+    """``moe_rows_issued``: on the compact path the rows its kernel
+    computes, the kept rows by ``ragged_dot`` and by the TPU's kernel those
+    rounded out to its row tiles, group by group (at most the capacity
+    rows); on the capacity paths the capacity rows."""
+    from repro.kernels.ragged import buffer_rows, row_tile
+
+    mc = MoEConfig(n_experts=8, top_k=2, d_expert=16)
+    params = init_moe(KEY, 32, mc)
+    # Enough rows a group that the kernel's row tiles stay below capacity.
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 1024, 32), jnp.float32)
+    T, cf = 2048, 4.0
+    C = _pair_capacity(T, mc, 1, cf)
+    if path == "compact_megablox":
+        _interpret_tpu_kernel(monkeypatch)
+    if path == "grouped":
+        impl = lambda p, x: moe_grouped(p, x, mc, cap=C)  # noqa: E731
+    else:
+        impl = _one_chip_ep(mc, cf, use_pallas=path == "pallas")
+    _, counts = _counted(impl)(params, x)
+    issued, slots = int(counts["moe_rows_issued"]), int(counts["moe_slots"])
+    assert slots == mc.e_total * C
+    if not path.startswith("compact"):
+        assert issued == slots
+        return
+    _, top_i = router_topk(params["router"], x.reshape(T, -1), mc)
+    kept = np.minimum(np.bincount(np.asarray(top_i).reshape(-1),
+                                  minlength=mc.e_total), C)
+    if path == "compact":
+        assert issued == kept.sum()
+        return
+    R = buffer_rows(T * mc.top_k)
+    assert issued == _tile_rows(kept, row_tile(R))
+    assert kept.sum() < issued <= slots
+
+
 def test_capacity_rounding():
     mc = MoEConfig(n_experts=8, top_k=2, d_expert=8, capacity_factor=1.0)
     assert capacity(100, mc, ep=4) % 4 == 0

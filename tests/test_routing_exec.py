@@ -251,7 +251,7 @@ def test_bridge_dropless_counts():
 
 
 def test_ep_pair_capacity_plan():
-    """parallel.ep.plan_from_dispatch mirrors _dispatch_buffers' slots."""
+    """parallel.ep.plan_from_dispatch mirrors _route_local's slots."""
     from repro.parallel.ep import plan_from_dispatch
     mc, params, x, top_p, top_i = _routed_case(7)
     ep, t_loc = 4, 8

@@ -113,12 +113,53 @@ def test_expert_kernel_compiles_for_v5e(kernel, shape_fn, precision,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_granite_train_step_compiles_for_one_v5e(one_chip):
+def _ragged_shapes(name):
+    """(E, R, K, F) of the one-chip cells' compact expert buffers: granite
+    at 2 x 2048 tokens, top-8; Moonlight's 8 held experts at 2 x 4096
+    tokens, top-6, capacity 3072 an expert."""
+    if name == "granite":
+        mc = get_config("granite-moe-3b-a800m").moe
+        return mc.e_total, SMOKE_TOKENS * mc.top_k, 1536, mc.d_expert
+    return 8, 8 * 3072, 2048, 1408
+
+
+@pytest.mark.parametrize("name", ["granite", "moonlight"])
+def test_ragged_expert_ffn_compiles_for_v5e(name, one_chip):
+    """The one-chip expert FFN over the compact buffer, forward and
+    backward, by the TPU's grouped-GEMM kernel at the cells' widths."""
+    from repro.kernels.ragged import buffer_rows, tpu_grouped_matmul
+    from repro.models.layers import glu_act
+
+    E, R, K, F = _ragged_shapes(name)
+    R = buffer_rows(R)
+
+    def arr(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def ffn_grads(x, w_in, w_down, sizes, dy):
+        def ffn(x, w_in, w_down):
+            h = tpu_grouped_matmul(x, w_in, sizes)
+            return tpu_grouped_matmul(glu_act(h, "swiglu"), w_down, sizes)
+        return jax.vjp(ffn, x, w_in, w_down)[1](dy)
+
+    with _time_limit(120):
+        compiled = jax.jit(ffn_grads).lower(
+            arr(R, K), arr(E, K, 2 * F), arr(E, F, K),
+            arr(E, dtype=jnp.int32), arr(R, K)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_granite_train_step_compiles_for_one_v5e(one_chip, monkeypatch):
     """One layer of granite-moe-3b-a800m at full width, trained on the
-    smoke's batch by the trainer's own step on one described chip."""
+    smoke's batch by the trainer's own step on one described chip, with
+    the expert GEMMs the chip runs (the process's backend is the CPU, so
+    the test selects the TPU kernel itself)."""
+    from repro.kernels import ragged
     from repro.launch.train import build_training
     from repro.optim import adamw
     from repro.parallel.ep import EPConfig
+
+    monkeypatch.setattr(ragged, "on_tpu", lambda: True)
 
     cfg = get_config("granite-moe-3b-a800m", n_layers=1)
     mesh = jax.sharding.Mesh(
@@ -132,3 +173,4 @@ def test_granite_train_step_compiles_for_one_v5e(one_chip):
         compiled = step.lower(*state_shape, batch_shapes).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+    assert "tpu_custom_call" in compiled.as_text()

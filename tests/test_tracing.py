@@ -5,7 +5,9 @@
   ones, for the EP ``hyperparallel`` path on one device and on four.
 * **MoE counters** — ``moe_dropped`` equals a numpy recount of the capacity
   drops from the router's ``top_i`` and ``C``, where capacity drops rows and
-  where it drops none, on the EP path and on ``moe_grouped``.
+  where it drops none, on the EP path and on ``moe_grouped``; the train
+  step's ``moe_rows_issued`` covers the kept rows on one device and equals
+  the capacity rows over four.
 * **loop spans** — ``train_loop`` under ``jax.profiler.trace`` writes one
   ``train_step`` span per step, with its step number, and the
   ``train_loop/*`` phase spans inside it, in order; the counters ride the
@@ -69,11 +71,15 @@ with jax.set_mesh(mesh):
         {k: (jnp.arange(2 * n * 64).reshape(2 * n, 64) * 7) % 500
          for k in ("tokens", "labels")}, fns.rules.batch_shardings(batch))
     _, _, m = compiled(params, opt, b)
+sys.path.insert(0, ".")
+from chipbench.layers import int_counters
 print(json.dumps({
     "op_names": sorted(set(re.findall(r'op_name="([^"]*)"',
                                       compiled.as_text()))),
     "counters": {k: [int(m[k]), str(m[k].dtype)] for k in
-                 ("moe_routed", "moe_dropped", "moe_slots")}}))
+                 ("moe_routed", "moe_dropped", "moe_slots",
+                  "moe_rows_issued")},
+    "int_counters": sorted(int_counters(jax.device_get(m)))}))
 """
 
 
@@ -111,6 +117,17 @@ def test_train_step_hlo_names_every_scope(devices):
     assert c["moe_routed"] == [2 * 2 * devices * 64 * 2, "int32"]
     assert c["moe_slots"] == [2 * devices * 8 * 32, "int32"]
     assert 0 <= c["moe_dropped"][0] <= c["moe_routed"][0]
+    # One device computes the kept rows in whole row tiles; four exchange
+    # fixed-size chunks and compute every capacity row.
+    issued, dtype = c["moe_rows_issued"]
+    assert dtype == "int32"
+    if devices == 1:
+        assert issued >= c["moe_routed"][0] - c["moe_dropped"][0]
+    else:
+        assert issued == c["moe_slots"][0]
+    # What the benchmark's result line carries as the step's counters.
+    assert {"moe_routed", "moe_dropped", "moe_slots",
+            "moe_rows_issued"} <= set(out["int_counters"])
 
 
 def _recount(top_i: np.ndarray, C: int) -> int:
@@ -198,3 +215,23 @@ def test_train_loop_writes_step_and_phase_spans(tmp_path):
     assert [{k: m[k] for k in ("moe_routed", "moe_dropped", "moe_slots")}
             for m in run.metrics_log] == [
         {"moe_routed": 16, "moe_dropped": 3, "moe_slots": 64}] * 2
+
+
+def test_traced_line_carries_rows_issued_on_cpu(tmp_path):
+    """A traced benchmark run on the CPU (the benchmark's tiny one-chip
+    cell) carries ``moe_rows_issued`` in its checked steps' counters: at
+    least the kept rows of the step's two MoE layers."""
+    if os.path.abspath(ROOT) not in map(os.path.abspath, sys.path):
+        sys.path.insert(0, os.path.abspath(ROOT))
+    from chipbench.tests import checkout
+
+    root = checkout.make(str(tmp_path))
+    p = checkout.run(root, ["--workload", checkout.CELL, "--seed",
+                            str(2 ** 41 + 5), "--seconds", "1",
+                            "--trace", "1"], plant=checkout.CPU_OPS)
+    assert p.returncode == 0, p.stderr[-3000:]
+    line = checkout.last_line(p.stdout)
+    assert line["correct"] is True, line["checks"]
+    assert [c["step"] for c in line["counters"]] == [0, 1, 2]
+    for c in line["counters"]:
+        assert c["moe_rows_issued"] >= c["moe_routed"] - c["moe_dropped"]
